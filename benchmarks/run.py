@@ -38,6 +38,8 @@ def main() -> None:
                     help="comma-separated bench keys to run")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch import platform
+    platform.enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

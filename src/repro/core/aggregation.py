@@ -19,7 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .losses import pinned_sum
+from .losses import HIGHEST, pinned_sum
 
 F32 = jnp.float32
 
@@ -79,7 +79,8 @@ def weighted_sa(local_probs: jax.Array, weights: jax.Array,
     if use_kernel and _kernel_eligible(local_probs):
         from repro.kernels import ops as kops
         return kops.weighted_mean(local_probs, w, interpret=interpret)
-    return jnp.einsum("k,k...->...", w, local_probs.astype(F32))
+    return jnp.einsum("k,k...->...", w, local_probs.astype(F32),
+                      precision=HIGHEST)
 
 
 def weighted_era(local_probs: jax.Array, weights: jax.Array,

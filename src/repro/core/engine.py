@@ -571,6 +571,19 @@ class FedEngine:
         from ..obs import engine_compile_counts
         return engine_compile_counts(self)
 
+    def compiled_hlo(self, state: RoundState, data) -> list[str]:
+        """Optimized HLO text of every round and fused-chunk program this
+        engine has built, compiled for ``state`` and ``data``'s plain
+        context (runs made with weights, a ctx plan or a sparse budget
+        built programs this does not lower).  What the device really runs:
+        a kernel's ``tpu_custom_call``, a cross-chip collective."""
+        rng = jax.random.PRNGKey(self.algo.hp.seed)
+        lowered = [fn.lower(state, self._payload_ctx(data), rng)
+                   for fn in self._round_cache.values()]
+        lowered += [fn.lower(state, self.make_ctx(data), rng, None)
+                    for fn in self._chunk_cache.values()]
+        return [lo.compile().as_text() for lo in lowered]
+
     # ------------------------------------------------------- checkpointing --
     def save_state(self, path: str, state: RoundState) -> None:
         import numpy as np

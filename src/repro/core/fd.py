@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from .client import LocalSpec, local_update, predict_probs
+from .losses import HIGHEST
 
 F32 = jnp.float32
 
@@ -26,7 +27,7 @@ def per_label_logits(apply_fn, params, state, x, y, n_classes: int):
     probs = predict_probs(apply_fn, params, state, x)       # (I, C)
     oh = jax.nn.one_hot(y, n_classes, dtype=F32)            # (I, C)
     counts = jnp.sum(oh, axis=0)                            # (C,)
-    sums = oh.T @ probs                                     # (C, C)
+    sums = jnp.dot(oh.T, probs, precision=HIGHEST)          # (C, C)
     t = sums / jnp.maximum(counts[:, None], 1.0)
     return t, counts > 0
 
@@ -41,8 +42,9 @@ def aggregate_fd(tk: jax.Array, present: jax.Array):
     bitwise-identical inputs, and a fused plain reduce is free to
     reassociate differently in each (see `losses.pinned_sum`)."""
     m = present.astype(F32)                                 # (K, C)
-    n_own = jnp.einsum("k,kc->c", jnp.ones((m.shape[0],), F32), m)
-    tg = jnp.einsum("kc,kcd->cd", m, tk.astype(F32)) \
+    n_own = jnp.einsum("k,kc->c", jnp.ones((m.shape[0],), F32), m,
+                       precision=HIGHEST)
+    tg = jnp.einsum("kc,kcd->cd", m, tk.astype(F32), precision=HIGHEST) \
         / jnp.maximum(n_own[:, None], 1.0)
     return tg, n_own
 

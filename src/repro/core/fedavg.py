@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from .client import LocalSpec, local_update
-from .losses import pinned_sum
+from .losses import HIGHEST, pinned_sum
 
 
 def weighted_average(stacked, weights: jax.Array):
@@ -24,8 +24,8 @@ def weighted_average(stacked, weights: jax.Array):
     w = w / pinned_sum(w)
 
     def avg(leaf):
-        return jnp.einsum("k,k...->...", w, leaf.astype(jnp.float32)
-                          ).astype(leaf.dtype)
+        return jnp.einsum("k,k...->...", w, leaf.astype(jnp.float32),
+                          precision=HIGHEST).astype(leaf.dtype)
 
     return jax.tree.map(avg, stacked)
 

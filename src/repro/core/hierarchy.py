@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from .aggregation import _kernel_eligible, _normalize_weights
+from .losses import HIGHEST
 
 F32 = jnp.float32
 
@@ -69,7 +70,7 @@ def hierarchical_weighted_sa(local_probs: jax.Array, weights: jax.Array,
         if use_kernel and _kernel_eligible(probs):
             from repro.kernels import ops as kops
             return kops.weighted_mean(probs, w, interpret=interpret)
-        return jnp.einsum("k,k...->...", w, probs)
+        return jnp.einsum("k,k...->...", w, probs, precision=HIGHEST)
     partials = []
     for start, end in edge_shards(probs.shape[0], n_edges):
         if use_kernel and _kernel_eligible(probs):
@@ -79,7 +80,8 @@ def hierarchical_weighted_sa(local_probs: jax.Array, weights: jax.Array,
                                                interpret=interpret))
         else:
             partials.append(jnp.einsum("k,k...->...", w[start:end],
-                                       probs[start:end]))
+                                       probs[start:end],
+                                       precision=HIGHEST))
     # server stage: fixed left-to-right edge order, so the tree's rounding
     # is at least deterministic across runs of the same topology
     total = partials[0]

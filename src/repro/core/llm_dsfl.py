@@ -27,7 +27,7 @@ from .aggregation import era, sa, topk_compress, weighted_era, weighted_sa
 from .hierarchy import hierarchical_weighted_era, hierarchical_weighted_sa
 from .algorithms import (active_indices, gather_clients, masked_mean,
                          scatter_clients, scatter_zeros, select_clients)
-from .losses import (distill_xent, pinned_sum, topk_distill_xent,
+from .losses import (HIGHEST, distill_xent, pinned_sum, topk_distill_xent,
                      xent_int_labels)
 
 
@@ -178,9 +178,8 @@ def dsfl_exchange(cfg: ModelConfig, stacked_params, open_batch,
         # and all-gathers the dense teacher (measured: 10 GB cross-pod).
         # A pod-axis shard_map pins the all-gather on the (value, index)
         # pairs — k*(4+4) bytes/token of inter-pod traffic.
-        _get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-        mesh = _get_mesh() if _get_mesh is not None else None
-        if mesh is not None and "pod" in mesh.axis_names:
+        mesh = jax.sharding.get_abstract_mesh()
+        if "pod" in mesh.axis_names:
             from jax.sharding import PartitionSpec as P
             sm = jax.shard_map(
                 lambda v, i: (jax.lax.all_gather(v[0], "pod"),
@@ -216,9 +215,10 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
         V = cfg.eff_vocab     # probs carry the padded (TP-divisible) vocab
         iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 1, V), 4)
         onehot = (iota == ti[..., None]).astype(jnp.float32)   # (Kc,B,S,k,V)
-        dense = jnp.einsum("cbsk,cbskv->cbsv", tv.astype(jnp.float32), onehot)
+        dense = jnp.einsum("cbsk,cbskv->cbsv", tv.astype(jnp.float32), onehot,
+                           precision=HIGHEST)
         dense = constrain(dense, None, "batch", None, "model")
-        teacher = _aggregate_teacher(dense, hp, weights)
+        teacher = aggregate_teacher(dense, hp, weights)
         teacher = constrain(teacher, "batch", None, "model")
         # the exchange leg is compressed; the pod-local distillation uses the
         # dense (vocab-sharded) teacher — no top_k over a sharded axis
@@ -226,7 +226,7 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
         hp = dataclasses.replace(hp, topk=None)
     else:
         (probs,) = inflight
-        teacher = _aggregate_teacher(probs, hp, weights)
+        teacher = aggregate_teacher(probs, hp, weights)
 
     new_params, losses = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
@@ -299,7 +299,7 @@ def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
     batches_m = gather_clients(private_batches, idx)
 
     (probs_m,) = inflight                                   # (m, B, S, V)
-    teacher = _aggregate_teacher(scatter_zeros(probs_m, K, idx), hp, weights)
+    teacher = aggregate_teacher(scatter_zeros(probs_m, K, idx), hp, weights)
 
     new_m, losses_m = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
@@ -310,7 +310,7 @@ def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
     return new_params, masked_mean(losses, act.astype(jnp.float32) > 0)
 
 
-def _aggregate_teacher(probs, hp: LLMDsflHP, weights):
+def aggregate_teacher(probs, hp: LLMDsflHP, weights):
     """sa/era over the client axis; the weighted variants zero out absent
     clients and decay stale ones when the sim supplies ``weights``.
     ``hp.agg_edges > 1`` reduces the client axis through the two-level
@@ -379,7 +379,8 @@ def fedavg_round_step(cfg: ModelConfig, stacked_params, private_batches,
         w = w / jnp.maximum(pinned_sum(w), 1e-9)
         avg = jax.tree.map(
             lambda leaf: jnp.einsum("k,k...->...", w,
-                                    leaf.astype(jnp.float32)
+                                    leaf.astype(jnp.float32),
+                                    precision=HIGHEST
                                     )[None].astype(leaf.dtype), new_params)
         m = (weights if mask is None else mask).astype(jnp.float32) > 0
         loss = masked_mean(losses, m)

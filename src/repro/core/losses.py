@@ -17,6 +17,9 @@ import jax
 import jax.numpy as jnp
 
 F32 = jnp.float32
+# for f32 contractions that stand for exact sums: the TPU would otherwise
+# run them as one bf16 pass (~3 significant digits); the CPU ignores it
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def pinned_sum(v):
@@ -24,7 +27,7 @@ def pinned_sum(v):
     two differently-fused programs summing bitwise-identical inputs agree
     bitwise (see module docstring).  Sums over *all* axes."""
     v = v.astype(F32).ravel()
-    return jnp.dot(v, jnp.ones_like(v))
+    return jnp.dot(v, jnp.ones_like(v), precision=HIGHEST)
 
 
 def pinned_mean(ce, mask=None):

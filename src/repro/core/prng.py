@@ -5,20 +5,18 @@ keeps only the sampled m clients resident, but the house RNG discipline
 derives client k's per-round key as row k of ``jax.random.split(r, K)`` —
 an O(K) array the million-client path must never materialize.
 
-Under JAX's default threefry PRNG the split *is* a counter-mode block
-cipher: ``split(key, K)`` encrypts the counters ``iota(2K)`` and reshapes
-the flat 2K-word ciphertext to (K, 2).  The threefry primitive consumes a
-flat even-length count array as two halves — element ``e`` of the flat
-output is word 0 of the encrypted counter pair ``(e, K + e)`` when
-``e < K`` and word 1 of the pair ``(e - K, e)`` otherwise — so any row k
-of the split is two cipher words computable from the counter values
-``2k`` and ``2k + 1`` alone.  `split_take` batches that: m rows cost one
-threefry call over 4m counters, independent of K, and the result is
-**bitwise** the corresponding rows of the dense split (pinned by
-``tests/test_cohort.py`` across odd/even K and hypothesis-drawn ids).
+Under JAX's default threefry PRNG with the partitionable layout (the
+default since jax 0.5, ``jax_threefry_partitionable=True``) the split *is*
+a counter-mode block cipher in which every row owns its counter: row k of
+``split(key, K)`` is the threefry2x32 encryption of the 64-bit counter k,
+i.e. of the word pair ``(hi, lo) = (0, k)``, independent of K.
+`split_take` batches that: m rows cost one threefry call over 2m counter
+words, and the result is **bitwise** the corresponding rows of the dense
+split (pinned by ``tests/test_cohort.py`` across odd/even K and
+hypothesis-drawn ids).
 
-Anything that is not a raw threefry key (typed keys of another impl, a
-non-default global impl) falls back to the dense
+Anything else (typed keys of another impl, a non-default global impl, or
+``jax_threefry_partitionable=False``) falls back to the dense
 ``jnp.take(jax.random.split(key, K), ids, axis=0)``.
 """
 from __future__ import annotations
@@ -29,7 +27,10 @@ import jax.numpy as jnp
 
 def _is_raw_threefry(key) -> bool:
     """Raw uint32 (2,) keys are threefry keys by construction (the repo's
-    ``jax.random.PRNGKey`` discipline); typed keys carry their impl."""
+    ``jax.random.PRNGKey`` discipline); typed keys carry their impl.  The
+    counter shortcut holds only under the partitionable split layout."""
+    if not jax.config.jax_threefry_partitionable:
+        return False
     if jnp.issubdtype(jnp.result_type(key), jax.dtypes.prng_key):
         return False
     return key.shape == (2,) and key.dtype == jnp.uint32
@@ -47,16 +48,11 @@ def split_rows(key, ids, num: int):
         return jnp.take(jax.random.split(key, num), jnp.asarray(ids), axis=0,
                         mode="clip")
     from jax.extend.random import threefry_2x32
-    ids = jnp.asarray(ids).astype(jnp.uint32)
-    num = jnp.uint32(num)
-    # flat ciphertext elements (2k, 2k+1) form row k of the (num, 2) split
-    e = jnp.stack([2 * ids, 2 * ids + 1], axis=-1).reshape(-1)      # (2m,)
-    lo = jnp.where(e < num, e, e - num)
-    # counts = [lo | lo+num]: the primitive encrypts halves pairwise, so
-    # out[:2m] are the pairs' first words and out[2m:] their second words
-    out = threefry_2x32(key, jnp.concatenate([lo, lo + num]))
-    words = jnp.where(e < num, out[: e.shape[0]], out[e.shape[0]:])
-    return words.reshape(ids.shape[0], 2)
+    lo = jnp.asarray(ids).astype(jnp.uint32)
+    # the primitive encrypts the halves of its count array pairwise:
+    # out[:m] are the rows' first words, out[m:] their second words
+    out = threefry_2x32(key, jnp.concatenate([jnp.zeros_like(lo), lo]))
+    return jnp.stack([out[: lo.shape[0]], out[lo.shape[0]:]], axis=-1)
 
 
 # the name the algorithms use: "take rows of split(key, num)"

@@ -81,12 +81,14 @@ distill_loss_2d.defvjp(_dl_fwd, _dl_bwd)
 
 def distill_loss(student_logits: jax.Array, teacher_probs: jax.Array,
                  mask=None, interpret: bool | None = None) -> jax.Array:
-    """Arbitrary leading dims; mask unsupported on the kernel path (falls back
-    to the reference implementation when given).  ``interpret=None`` = auto
-    (CPU -> interpret, else the compiled kernel)."""
+    """Arbitrary leading dims.  The kernel has no row mask: a masked loss
+    raises rather than quietly running the reference (masked callers use
+    `core.losses.softmax_xent`).  ``interpret=None`` = auto (CPU ->
+    interpret, else the compiled kernel)."""
     if mask is not None:
-        from ..core.losses import softmax_xent
-        return softmax_xent(student_logits, teacher_probs, mask)
+        raise NotImplementedError(
+            "the fused distill_loss kernel takes no mask; use "
+            "core.losses.softmax_xent for a masked distillation loss")
     V = student_logits.shape[-1]
     z = student_logits.reshape(-1, V)
     t = teacher_probs.reshape(-1, V)

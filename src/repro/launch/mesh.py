@@ -5,13 +5,9 @@ from __future__ import annotations
 import jax
 
 
-def _mk(shape, axes):
-    # jax < 0.5 has no jax.sharding.AxisType (axes are implicitly Auto)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+def _mk(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,13 +19,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _mk(shape, axes)
 
 
-def make_client_mesh(n_clients: int):
-    """("pod", "data", "model") mesh over however many real devices exist,
-    with the federated-client axis on "pod" when the device count divides
-    (the 8-fake-device CI tier; collapses to (1, 1, n) on one device)."""
-    n = len(jax.devices())
+def make_client_mesh(n_clients: int, devices=None):
+    """("pod", "data", "model") mesh over ``devices`` (default: every real
+    device), with the federated-client axis on "pod" when the device count
+    divides (one client per chip on a 4-chip host, the 8-fake-device CI
+    tier; collapses to (1, 1, n) on one device).  Passing ``devices`` lets
+    one process build meshes over different device subsets side by side."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = len(devices)
     pod = n_clients if n >= n_clients and n % n_clients == 0 else 1
-    return _mk((pod, 1, n // pod), ("pod", "data", "model"))
+    return _mk((pod, 1, n // pod), ("pod", "data", "model"), devices)
 
 
 def make_smoke_mesh(*, multi_pod: bool = False):

@@ -47,19 +47,19 @@ import warnings
 from dataclasses import dataclass
 from typing import Optional, Union
 
-# the SNIPPET-1 (bayespec) GPU tuning set, modernized for this jaxlib: the
-# latency-hiding scheduler + pipelined collectives are what let an
+# the SNIPPET-1 (bayespec) GPU tuning set, modernized for jaxlib 0.9: the
+# latency-hiding scheduler + pipelined all-gather are what let an
 # all-gather overlap compute (XLA dropped the older
-# ``--xla_gpu_enable_async_*`` spellings, and an unknown flag is FATAL at
-# backend init — parse_flags_from_env aborts — so this set is pinned to
-# flags the pinned jaxlib actually knows); the triton fusions ride along
-# for the softmax/gemm-heavy ERA path
+# ``--xla_gpu_enable_async_*``, ``--xla_gpu_enable_pipelined_collectives``
+# and ``--xla_gpu_enable_triton_softmax_fusion`` spellings, and an unknown
+# flag is FATAL at backend init — parse_flags_from_env aborts — so this set
+# is pinned to flags the installed jaxlib knows; tests/test_overlap.py
+# starts a backend under it); the triton gemm fusion rides along for the
+# gemm-heavy ERA path
 _OVERLAP_FLAGS = (
     "--xla_gpu_enable_latency_hiding_scheduler=true",
     "--xla_gpu_enable_highest_priority_async_stream=true",
     "--xla_gpu_enable_pipelined_all_gather=true",
-    "--xla_gpu_enable_pipelined_collectives=true",
-    "--xla_gpu_enable_triton_softmax_fusion=true",
     "--xla_gpu_triton_gemm_any=True",
 )
 
@@ -87,8 +87,8 @@ PRESETS = {
         "(exercises pod-sharded collectives without an accelerator)",
         host_device_count=8),
     "overlap": PlatformPreset(
-        "overlap", "latency-hiding scheduler + async all-gather/"
-        "collectives + triton fusion (SNIPPET-1 bayespec set): lets the "
+        "overlap", "latency-hiding scheduler + pipelined all-gather "
+        "+ triton gemm fusion (SNIPPET-1 bayespec set): lets the "
         "pipelined exchange actually hide behind compute off-CPU",
         xla_flags=_OVERLAP_FLAGS),
     "overlap-cpu8": PlatformPreset(
@@ -180,6 +180,29 @@ def from_args(args) -> Optional[PlatformPreset]:
     the top of ``main`` — before building engines or touching devices."""
     name = getattr(args, "platform_preset", None)
     return apply(name) if name else None
+
+
+# --------------------------------------------------------- compile cache ----
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    already reads it and nothing is overridden; otherwise the cache is
+    ``.jax_cache/`` at the root of the checkout, resolved from this file's
+    location so every run of one checkout finds the same directory (the
+    path is part of the cache key; gitignored).  Entry points call this
+    (``chip_smoke.py``, the ``launch.train``/``launch.serve`` CLIs,
+    ``benchmarks.run``); importing the package never does, so test
+    processes that compile for a described chip write no cache entries
+    they could not read back."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))))
+        path = os.path.join(root, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_enable_compilation_cache", True)
+    return path
 
 
 # ------------------------------------------------ did-the-scheduler-fire ----

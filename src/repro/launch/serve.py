@@ -124,6 +124,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     # preset before backend init: XLA_FLAGS are read once
     platform.from_args(args)
+    platform.enable_compile_cache()
     with obs_cli.session(args):
         run(args)
 

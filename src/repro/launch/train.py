@@ -44,6 +44,7 @@ On this CPU container use ``--smoke`` (reduced config).  Example:
 from __future__ import annotations
 
 import argparse
+import functools
 import time
 
 import jax
@@ -58,7 +59,7 @@ from ..core.llm_algorithms import (LLMDSFLAlgorithm, LLMFedAvgAlgorithm,
 from ..core.llm_dsfl import LLMDsflHP, sgd_train_step
 from ..data.pipeline import build_lm_task, lm_open_batch
 from ..models.api import model_init
-from ..models.base import param_count
+from ..models.base import ModelConfig, param_count
 from ..models.shardctx import axis_ctx
 from ..checkpoint import save_pytree
 from ..obs import cli as obs_cli
@@ -79,7 +80,7 @@ def extra_inputs(cfg, batch, key):
     return out
 
 
-def main(argv=None):
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-4b", choices=list_archs())
     ap.add_argument("--mode", default="dsfl",
@@ -116,12 +117,49 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     platform.add_args(ap)
     obs_cli.add_args(ap)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = make_parser().parse_args(argv)
     # apply the XLA preset BEFORE anything touches the backend (obs session
     # provenance included) — XLA_FLAGS are read once at backend init
     platform.from_args(args)
+    platform.enable_compile_cache()
     with obs_cli.session(args):
         run(args)
+
+
+def build_federated(cfg: ModelConfig, args, mesh=None):
+    """The objects a federated run (``--mode dsfl|fedavg``) drives, for any
+    `ModelConfig`: ``(engine, initial state, task)``.  The engine jits the
+    round on ``mesh`` (default `make_client_mesh(args.clients)`: the client
+    axis on "pod" when the devices allow) and donates the round state."""
+    K = args.clients
+    task = build_lm_task(args.seed, K, args.batch, args.seq, cfg.vocab,
+                         extras_fn=lambda b, k: extra_inputs(cfg, b, k))
+    if args.mode == "dsfl":
+        hp = LLMDsflHP(lr=args.lr, gamma=args.gamma,
+                       aggregation=args.aggregation, topk=args.topk,
+                       rounds=args.steps, seed=args.seed,
+                       open_batch=args.batch)
+        algo = LLMDSFLAlgorithm(cfg, hp)
+        # the wire leg: top-k (value, index) pairs when sparsified, else
+        # half-precision logits (probs travel as bf16 — 2 bytes each)
+        codec = (wire.TopKCodec(k=args.topk, n_classes=cfg.vocab)
+                 if args.topk else wire.FP16Codec())
+    else:
+        algo = LLMFedAvgAlgorithm(cfg, LLMFedAvgHP(
+            lr=args.lr, rounds=args.steps, seed=args.seed))
+        codec = wire.DenseF32Codec()
+    eng = FedEngine(algo, codec=codec,
+                    mesh=make_client_mesh(K) if mesh is None else mesh,
+                    donate_state=True)
+    # a jitted init: each leaf's f32 draw fuses into its bf16 cast instead
+    # of materializing whole on the device
+    state = eng.init(jax.jit(functools.partial(model_init, cfg)), task,
+                     rng=jax.random.PRNGKey(args.seed))
+    return eng, state, task
 
 
 def run(args):
@@ -134,25 +172,8 @@ def run(args):
     print(f"arch={cfg.name} ({cfg.arch_type}) layers={cfg.n_layers} "
           f"d={cfg.d_model} vocab={cfg.vocab}")
     if args.mode in ("dsfl", "fedavg"):
-        task = build_lm_task(args.seed, K, args.batch, args.seq, cfg.vocab,
-                             extras_fn=lambda b, k: extra_inputs(cfg, b, k))
-        if args.mode == "dsfl":
-            hp = LLMDsflHP(lr=args.lr, gamma=args.gamma,
-                           aggregation=args.aggregation, topk=args.topk,
-                           rounds=args.steps, seed=args.seed,
-                           open_batch=args.batch)
-            algo = LLMDSFLAlgorithm(cfg, hp)
-            # the wire leg: top-k (value, index) pairs when sparsified, else
-            # half-precision logits (probs travel as bf16 — 2 bytes each)
-            codec = (wire.TopKCodec(k=args.topk, n_classes=cfg.vocab)
-                     if args.topk else wire.FP16Codec())
-        else:
-            algo = LLMFedAvgAlgorithm(cfg, LLMFedAvgHP(
-                lr=args.lr, rounds=args.steps, seed=args.seed))
-            codec = wire.DenseF32Codec()
-        mesh = make_client_mesh(K)
-        eng = FedEngine(algo, codec=codec, mesh=mesh, donate_state=True)
-        state = eng.init(lambda k: model_init(cfg, k), task, rng=key)
+        eng, state, task = build_federated(cfg, args)
+        mesh = eng.mesh
         one = jax.tree.map(lambda a: a[0], state.clients.params)
         print(f"params/client: {param_count(one):,}")
         # measured per-round exchange bytes (eval_shape: no compute), the

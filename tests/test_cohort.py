@@ -95,6 +95,17 @@ def test_split_take_typed_key_falls_back_and_matches():
         np.asarray(jax.random.key_data(want)))
 
 
+def test_split_take_non_partitionable_layout_falls_back_and_matches():
+    """Under ``jax_threefry_partitionable=False`` a row of the split is not
+    its own counter's encryption: the dense fallback keeps the rows exact."""
+    key = jax.random.PRNGKey(7)
+    ids = np.array([4, 1, 1, 0], np.int64)
+    with jax.threefry_partitionable(False):
+        got = np.asarray(split_take(key, ids, 5))
+        want = np.asarray(jax.random.split(key, 5))[ids]
+    np.testing.assert_array_equal(got, want)
+
+
 # ------------------------------------------------------ lazy init parity -----
 def test_init_cohort_rows_match_dense_init_stack(task):
     """Client g's fresh state is a function of (rng, g) alone: slab rows

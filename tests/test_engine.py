@@ -119,6 +119,26 @@ def test_on_round_hook_can_rewrite_state(task, rng):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_compiled_hlo_covers_round_and_chunk_programs(task, client_params):
+    """One program text per built round and fused chunk, lowered from the
+    engine's own caches; building them again compiles nothing new."""
+    wk, sk, wg, sg = client_params
+    hp = DSFLConfig(rounds=1, local_epochs=1, distill_epochs=1, batch_size=40,
+                    open_batch=80, aggregation="era")
+    eng = FedEngine(DSFLAlgorithm(apply_mnist_cnn, hp, use_kernel=True))
+    state = eng.algo.init_from(wk, sk, wg, sg)
+    assert eng.compiled_hlo(state, task) == []
+    state = eng.run(state, task, rounds=1)
+    state = eng.run(state, task, rounds=2, chunk_rounds=2)
+    texts = eng.compiled_hlo(state, task)
+    assert len(texts) == 2
+    assert texts[0].startswith("HloModule jit_round")
+    assert texts[1].startswith("HloModule jit_chunk_fn")
+    counts = eng.compile_counts()
+    eng.compiled_hlo(state, task)
+    assert eng.compile_counts() == counts
+
+
 # ------------------------------------------------- adaptive weighted-ERA ----
 def test_weighted_era_learns_to_downweight_label_flipped_client(task,
                                                                 client_params):

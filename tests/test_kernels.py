@@ -177,17 +177,22 @@ def test_weighted_era_all_zero_weights_fall_back_to_uniform(rng):
 
 
 @pytest.mark.parametrize("N,V,bn,bv", [(32, 128, 8, 32), (64, 1024, 16, 256),
-                                       (128, 512, 128, 512), (8, 64, 8, 16)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+                                       (128, 512, 128, 512), (8, 64, 8, 16),
+                                       (40, 1000, 16, 256), (13, 300, 8, 128)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])
 def test_distill_loss_sweep(rng, N, V, bn, bv, dtype):
+    """The last two shapes pad both the row and the vocab axis."""
     k1, k2 = jax.random.split(rng)
     z = (jax.random.normal(k1, (N, V)) * 4).astype(dtype)
     t = jax.nn.softmax(jax.random.normal(k2, (N, V)), -1).astype(dtype)
     losses, logz = distill_loss_fwd_pallas(z, t, block_n=bn, block_v=bv,
                                            interpret=True)
     exp = ref.distill_loss_ref(z, t)
-    atol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    atol = 1e-4 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(losses, exp, atol=atol, rtol=1e-3)
+    x = z.astype(jnp.float32)
+    lz = jax.nn.logsumexp(x, axis=-1)
+    np.testing.assert_allclose(logz, lz, atol=1e-4, rtol=1e-5)
 
 
 def test_distill_loss_grad_matches_ref(rng):
@@ -206,6 +211,28 @@ def test_distill_loss_grad_matches_autodiff_of_ref(rng):
     g_kernel = jax.grad(lambda z_: ops.distill_loss(z_, t))(z)
     g_auto = jax.grad(lambda z_: jnp.mean(ref.distill_loss_ref(z_, t)))(z)
     np.testing.assert_allclose(g_kernel, g_auto, atol=1e-5)
+
+
+@pytest.mark.parametrize("N,V", [(300, 3000), (257, 2049)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])
+def test_distill_loss_padded_loss_and_grad_match_ref(rng, N, V, dtype):
+    """Through `ops.distill_loss` at its default 256 x 2048 blocks, which
+    divide neither axis: both shapes pad to 512 x 4096, so padded rows and
+    columns must add nothing to the loss and receive no gradient."""
+    k1, k2 = jax.random.split(rng)
+    z = (jax.random.normal(k1, (N, V)) * 3).astype(dtype)
+    t = jax.nn.softmax(jax.random.normal(k2, (N, V)), -1).astype(dtype)
+    loss, g = jax.value_and_grad(lambda z_: ops.distill_loss(z_, t))(z)
+    assert g.shape == (N, V) and g.dtype == dtype
+    np.testing.assert_allclose(loss, jnp.mean(ref.distill_loss_ref(z, t)),
+                               rtol=1e-5)
+    ge = ref.distill_loss_grad_ref(z, t, jnp.float32(1.0))
+    # the kernel rounds its f32 gradient to the input dtype once (gradients
+    # of ~1e-6 are float16 subnormals, spaced by its smallest subnormal)
+    fi = jnp.finfo(dtype)
+    rtol = 1e-5 if dtype == jnp.float32 else float(fi.eps)
+    np.testing.assert_allclose(np.asarray(g, np.float32), ge, rtol=rtol,
+                               atol=max(1e-9, float(fi.smallest_subnormal)))
 
 
 @pytest.mark.parametrize("M,Q,H,P,G,N", [

@@ -89,6 +89,50 @@ def test_apply_is_idempotent(clean_platform):
     assert os.environ["XLA_FLAGS"] == once
 
 
+def _child(code: str, **env) -> str:
+    """Run ``code`` in a fresh CPU python (XLA_FLAGS are read once, at
+    backend init, so a flag set can only be tried in a new process)."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    full = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+                JAX_PLATFORMS="cpu")
+    full.pop("JAX_COMPILATION_CACHE_DIR", None)
+    full.update(env)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=full, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return r.stdout.strip()
+
+
+def test_overlap_flags_start_a_cpu_backend():
+    """Every flag of the ``overlap`` preset is known to the installed
+    jaxlib: an unknown one aborts backend init (parse_flags_from_env)."""
+    flags = " ".join(pf.PRESETS["overlap"].xla_flags)
+    out = _child("import jax, jax.numpy as jnp; "
+                 "print(float(jnp.ones(3).sum()), jax.devices()[0].platform)",
+                 XLA_FLAGS=flags)
+    assert out.splitlines()[-1] == "3.0 cpu"
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_lands_where_asked(tmp_path, env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX; without it the
+    cache is the checkout's fixed ``.jax_cache/``."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    out = _child("import jax; from repro.launch import platform as pf; "
+                 "d = pf.enable_compile_cache(); "
+                 "print(d); print(jax.config.jax_compilation_cache_dir)",
+                 **env)
+    returned, configured = out.splitlines()[-2:]
+    want = (str(tmp_path / env_dir) if env_dir is not None
+            else os.path.join(root, ".jax_cache"))
+    assert returned == configured == want
+
+
 def test_apply_after_backend_init_warns(clean_platform, monkeypatch):
     monkeypatch.setattr(pf, "backend_initialized", lambda: True)
     with pytest.warns(UserWarning, match="after jax backend init"):
