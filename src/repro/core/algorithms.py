@@ -52,6 +52,16 @@ from .protocol import DSFLConfig  # noqa: F401  (re-exported as part of the API)
 
 EMPTY = ()   # absent pytree slot (contributes no leaves)
 
+# `jax.named_scope` names of the DS-FL round's steps: op metadata only (the
+# HLO ops, and so every result, are the same with or without them); the
+# device trace keeps them on each op, where the benchmark splits the
+# round's device time by step
+UPDATE = "dsfl_update"                  # 1. local update on private data
+PREDICT = "dsfl_predict"                # 2. prediction on o_r
+AGGREGATE = "dsfl_aggregate"            # 3-5. teacher: SA / ERA
+DISTILL = "dsfl_distill"                # 6. clients' distillation
+SERVER_DISTILL = "dsfl_server_distill"  # 6'. the server's distillation
+
 
 def _pytree_dataclass(cls=None, *, meta=()):
     """Register a frozen dataclass as a JAX pytree.  ``meta`` names fields
@@ -398,24 +408,28 @@ class DSFLAlgorithm:
         if self._is_sparse(ctx):
             return self._sparse_start(state, ctx, rng, ctx.active_budget)
         r1, _r2, r3, _r4 = jax.random.split(rng, 4)
-        xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
+        with jax.named_scope(PREDICT):
+            xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
 
         # 1. Update (always computed for the full stack — a fused where keeps
         # absent clients' state; no per-client Python loop, shards cleanly)
-        wk_n, sk_n, ouk_n, up_loss = jax.vmap(
-            lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk, yk, rk)
-        )(wk, sk, ouk, ctx.x, ctx.y, client_keys(r1, ctx, K))
-        if masked:
-            wk, sk, ouk = select_clients(ctx.mask, (wk_n, sk_n, ouk_n),
-                                         (wk, sk, ouk))
-        else:
-            wk, sk, ouk = wk_n, sk_n, ouk_n
+        with jax.named_scope(UPDATE):
+            wk_n, sk_n, ouk_n, up_loss = jax.vmap(
+                lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk,
+                                                         yk, rk)
+            )(wk, sk, ouk, ctx.x, ctx.y, client_keys(r1, ctx, K))
+            if masked:
+                wk, sk, ouk = select_clients(ctx.mask, (wk_n, sk_n, ouk_n),
+                                             (wk, sk, ouk))
+            else:
+                wk, sk, ouk = wk_n, sk_n, ouk_n
 
         # 2. Prediction (local logits on o_r)
-        probs = jax.vmap(lambda w, s: predict_probs(self.apply_fn, w, s, xo)
-                         )(wk, sk)
-        if self.corrupt is not None:
-            probs = self.corrupt(probs, xo, r3)
+        with jax.named_scope(PREDICT):
+            probs = jax.vmap(lambda w, s: predict_probs(self.apply_fn, w, s,
+                                                        xo))(wk, sk)
+            if self.corrupt is not None:
+                probs = self.corrupt(probs, xo, r3)
         return (wk, sk, ouk, up_loss, probs)
 
     def round_finish(self, state: RoundState, ctx: BatchCtx, inflight, rng):
@@ -433,51 +447,58 @@ class DSFLAlgorithm:
             return self._sparse_finish(state, ctx, inflight, rng,
                                        ctx.active_budget)
         _r1, r2, _r3, r4 = jax.random.split(rng, 4)
-        xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
+        with jax.named_scope(PREDICT):
+            xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
         wk, sk, ouk, up_loss, probs = inflight
 
         # 3-5. Upload / Aggregation / Broadcast
-        if masked:
-            pw, global_logit, sa_entropy = self._masked_teacher(probs, ctx)
-        else:
-            agg_w = self.agg_weights
-            if agg_w is None and hp.aggregation == "weighted_era":
-                # adaptive reliability (paper §5 "future work"): inverse mean
-                # entropy of each client's uploaded soft labels, re-estimated
-                # every round — diffuse (unreliable) uploads get down-weighted
-                ent_k = jnp.mean(entropy(probs), axis=-1)       # (K,)
-                agg_w = 1.0 / (ent_k + 1e-3)
-            pw = agg_w
-            if self.agg_edges > 1:
-                w = (jnp.ones((K,), jnp.float32) if agg_w is None else agg_w)
-                global_logit = (
-                    hierarchical_weighted_sa(probs, w, self.agg_edges,
-                                             use_kernel=self.use_kernel)
-                    if hp.aggregation == "sa"
-                    else hierarchical_weighted_era(
-                        probs, w, hp.temperature, self.agg_edges,
-                        use_kernel=self.use_kernel))
+        with jax.named_scope(AGGREGATE):
+            if masked:
+                pw, global_logit, sa_entropy = self._masked_teacher(probs,
+                                                                    ctx)
             else:
-                global_logit = aggregate(probs, hp.aggregation,
-                                         hp.temperature, weights=agg_w,
-                                         use_kernel=self.use_kernel)
-            sa_entropy = jnp.mean(entropy(jnp.mean(probs, axis=0)))
-        g_entropy = jnp.mean(entropy(global_logit))
+                agg_w = self.agg_weights
+                if agg_w is None and hp.aggregation == "weighted_era":
+                    # adaptive reliability (paper §5 "future work"): inverse
+                    # mean entropy of each client's uploaded soft labels,
+                    # re-estimated every round — diffuse (unreliable)
+                    # uploads get down-weighted
+                    ent_k = jnp.mean(entropy(probs), axis=-1)   # (K,)
+                    agg_w = 1.0 / (ent_k + 1e-3)
+                pw = agg_w
+                if self.agg_edges > 1:
+                    w = (jnp.ones((K,), jnp.float32) if agg_w is None
+                         else agg_w)
+                    global_logit = (
+                        hierarchical_weighted_sa(probs, w, self.agg_edges,
+                                                 use_kernel=self.use_kernel)
+                        if hp.aggregation == "sa"
+                        else hierarchical_weighted_era(
+                            probs, w, hp.temperature, self.agg_edges,
+                            use_kernel=self.use_kernel))
+                else:
+                    global_logit = aggregate(probs, hp.aggregation,
+                                             hp.temperature, weights=agg_w,
+                                             use_kernel=self.use_kernel)
+                sa_entropy = jnp.mean(entropy(jnp.mean(probs, axis=0)))
+            g_entropy = jnp.mean(entropy(global_logit))
 
         # 6. Distillation (clients, Eq. 10; absent clients keep their state)
-        wk_n, sk_n, odk_n, d_loss = jax.vmap(
-            lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
-                                              global_logit, rk)
-        )(wk, sk, odk, client_keys(r2, ctx, K))
-        if masked:
-            wk, sk, odk = select_clients(ctx.mask, (wk_n, sk_n, odk_n),
-                                         (wk, sk, odk))
-        else:
-            wk, sk, odk = wk_n, sk_n, odk_n
+        with jax.named_scope(DISTILL):
+            wk_n, sk_n, odk_n, d_loss = jax.vmap(
+                lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
+                                                  global_logit, rk)
+            )(wk, sk, odk, client_keys(r2, ctx, K))
+            if masked:
+                wk, sk, odk = select_clients(ctx.mask, (wk_n, sk_n, odk_n),
+                                             (wk, sk, odk))
+            else:
+                wk, sk, odk = wk_n, sk_n, odk_n
 
         # 6'. server global model (Eq. 11), with its own key r4
-        wg, sg, odg, gd_loss = local_distill(spec_d, wg, sg, odg, xo,
-                                             global_logit, r4)
+        with jax.named_scope(SERVER_DISTILL):
+            wg, sg, odg, gd_loss = local_distill(spec_d, wg, sg, odg, xo,
+                                                 global_logit, r4)
 
         metrics = {"update_loss": (masked_mean(up_loss, ctx.mask) if masked
                                    else jnp.mean(up_loss)),
@@ -516,28 +537,30 @@ class DSFLAlgorithm:
         # identical key discipline to the dense round (r3 would feed
         # `corrupt`, which forces the dense path; split to keep key parity)
         r1, _r2, _r3, _r4 = jax.random.split(rng, 4)
-        xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
-
-        idx = active_indices(ctx.mask, m)
-        mask_m = jnp.take(ctx.mask, idx, axis=0)
-        x_m, y_m = gather_clients((ctx.x, ctx.y), idx)
-        wk_m, sk_m, ouk_m = gather_clients((wk, sk, ouk), idx)
+        with jax.named_scope(PREDICT):
+            xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
 
         # 1. Update — only the gathered lanes; per-client keys gathered out
         # of the same (K,) split the dense round draws, so every active
         # client consumes bitwise its dense-path key
-        wk_n, sk_n, ouk_n, up_loss = jax.vmap(
-            lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk, yk,
-                                                     rk)
-        )(wk_m, sk_m, ouk_m, x_m, y_m,
-          jnp.take(client_keys(r1, ctx, K), idx, axis=0))
-        wk_m, sk_m, ouk_m = select_clients(mask_m, (wk_n, sk_n, ouk_n),
-                                           (wk_m, sk_m, ouk_m))
+        with jax.named_scope(UPDATE):
+            idx = active_indices(ctx.mask, m)
+            mask_m = jnp.take(ctx.mask, idx, axis=0)
+            x_m, y_m = gather_clients((ctx.x, ctx.y), idx)
+            wk_m, sk_m, ouk_m = gather_clients((wk, sk, ouk), idx)
+            wk_n, sk_n, ouk_n, up_loss = jax.vmap(
+                lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk,
+                                                         yk, rk)
+            )(wk_m, sk_m, ouk_m, x_m, y_m,
+              jnp.take(client_keys(r1, ctx, K), idx, axis=0))
+            wk_m, sk_m, ouk_m = select_clients(mask_m, (wk_n, sk_n, ouk_n),
+                                               (wk_m, sk_m, ouk_m))
 
         # 2. Prediction on the active lanes (the finish leg scatters into
         # exact zeros so the masked aggregation sees its (K, n, C) stack)
-        probs_m = jax.vmap(lambda w, s: predict_probs(self.apply_fn, w, s, xo)
-                           )(wk_m, sk_m)
+        with jax.named_scope(PREDICT):
+            probs_m = jax.vmap(lambda w, s: predict_probs(self.apply_fn, w,
+                                                          s, xo))(wk_m, sk_m)
         return (wk_m, sk_m, ouk_m, up_loss, probs_m)
 
     def _sparse_finish(self, state: RoundState, ctx: BatchCtx, inflight,
@@ -552,32 +575,40 @@ class DSFLAlgorithm:
         odg = state.server.opt_distill
         K = ctx.x.shape[0]
         _r1, r2, _r3, r4 = jax.random.split(rng, 4)
-        xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
+        with jax.named_scope(PREDICT):
+            xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
 
-        idx = active_indices(ctx.mask, m)
-        mask_m = jnp.take(ctx.mask, idx, axis=0)
-        odk_m = gather_clients(odk, idx)
+        with jax.named_scope(AGGREGATE):
+            idx = active_indices(ctx.mask, m)
+        with jax.named_scope(DISTILL):
+            mask_m = jnp.take(ctx.mask, idx, axis=0)
+            odk_m = gather_clients(odk, idx)
         wk_m, sk_m, ouk_m, up_loss, probs_m = inflight
-        probs = scatter_zeros(probs_m, K, idx)
 
         # 3-5. verbatim the dense masked aggregation on the scattered stack
-        pw, global_logit, sa_entropy = self._masked_teacher(probs, ctx)
-        g_entropy = jnp.mean(entropy(global_logit))
+        with jax.named_scope(AGGREGATE):
+            probs = scatter_zeros(probs_m, K, idx)
+            pw, global_logit, sa_entropy = self._masked_teacher(probs, ctx)
+            g_entropy = jnp.mean(entropy(global_logit))
 
         # 6. Distillation (clients) on the gathered lanes
-        wk_n, sk_n, odk_n, d_loss = jax.vmap(
-            lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
-                                              global_logit, rk)
-        )(wk_m, sk_m, odk_m, jnp.take(client_keys(r2, ctx, K), idx, axis=0))
-        wk_m, sk_m, odk_m = select_clients(mask_m, (wk_n, sk_n, odk_n),
-                                           (wk_m, sk_m, odk_m))
+        with jax.named_scope(DISTILL):
+            wk_n, sk_n, odk_n, d_loss = jax.vmap(
+                lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
+                                                  global_logit, rk)
+            )(wk_m, sk_m, odk_m,
+              jnp.take(client_keys(r2, ctx, K), idx, axis=0))
+            wk_m, sk_m, odk_m = select_clients(mask_m, (wk_n, sk_n, odk_n),
+                                               (wk_m, sk_m, odk_m))
 
         # 6'. server global model (Eq. 11), with its own key r4
-        wg, sg, odg, gd_loss = local_distill(spec_d, wg, sg, odg, xo,
-                                             global_logit, r4)
+        with jax.named_scope(SERVER_DISTILL):
+            wg, sg, odg, gd_loss = local_distill(spec_d, wg, sg, odg, xo,
+                                                 global_logit, r4)
 
-        clients = ClientState(*scatter_clients(
-            (wk_m, sk_m, ouk_m, odk_m), (wk, sk, ouk, odk), idx))
+        with jax.named_scope(DISTILL):
+            clients = ClientState(*scatter_clients(
+                (wk_m, sk_m, ouk_m, odk_m), (wk, sk, ouk, odk), idx))
         metrics = {"update_loss": masked_mean(scatter_zeros(up_loss, K, idx),
                                               ctx.mask),
                    "distill_loss": masked_mean(scatter_zeros(d_loss, K, idx),

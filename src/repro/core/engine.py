@@ -107,7 +107,6 @@ class FedEngine:
         self._round = None       # manual override slot (None = use the cache)
         self._round_cache = {}   # (state, ctx) treedef -> jitted round
         self._chunk_cache = {}   # scan signature -> jitted k-round driver
-        self._round_us = {}      # schedule (overlap?) -> per-round µs samples
 
     def _build_round(self, state: RoundState, ctx: BatchCtx):
         kw = {}
@@ -395,15 +394,16 @@ class FedEngine:
                 stacklevel=2)
         fn = None
         for r in range(start, start + rounds):
-            rng, rk, ri = jax.random.split(rng, 3)
-            o_idx = (jax.random.choice(ri, n_open, (n_r,), replace=False)
-                     if self.algo.uses_open else EMPTY)
-            ctx = self.make_ctx(data, o_idx=o_idx, weights=weights,
-                                active_budget=active_budget, cohort=cohort,
-                                population=population)
-            if ctx_plan is not None:
-                ctx = dataclasses.replace(
-                    ctx, **{f: v[r - start] for f, v in ctx_plan.items()})
+            with obs.span("engine.sample", "engine", round=r):
+                rng, rk, ri = jax.random.split(rng, 3)
+                o_idx = (jax.random.choice(ri, n_open, (n_r,), replace=False)
+                         if self.algo.uses_open else EMPTY)
+                ctx = self.make_ctx(data, o_idx=o_idx, weights=weights,
+                                    active_budget=active_budget,
+                                    cohort=cohort, population=population)
+                if ctx_plan is not None:
+                    ctx = dataclasses.replace(
+                        ctx, **{f: v[r - start] for f, v in ctx_plan.items()})
             if self.on_ctx is not None:
                 # externally-supplied client subsets: a `repro.sim` scheduler
                 # (or any caller) rewrites the ctx — participation mask,
@@ -424,9 +424,10 @@ class FedEngine:
                 if self.on_chunk is not None:
                     self.on_chunk(self.rounds_done, state)
                 if (r + 1) % log_every == 0:
-                    rec = {"round": r + 1,
-                           **{k: float(v) for k, v in m.items()
-                              if jnp.ndim(v) == 0}}
+                    with obs.span("engine.sync", "engine"):
+                        rec = {"round": r + 1,
+                               **{k: float(v) for k, v in m.items()
+                                  if jnp.ndim(v) == 0}}
                     if self.eval_fn is not None:
                         with obs.span("engine.eval", "engine"):
                             rec.update(self.eval_fn(
@@ -450,7 +451,6 @@ class FedEngine:
                      rng, chunk, ctx_plan, n_open, n_r, active_budget=None,
                      cohort=EMPTY, population=None,
                      overlap: bool = False) -> RoundState:
-        import time
         r, end, n_chunks = start, start + rounds, 0
         while r < end:
             k = min(chunk, end - r)
@@ -462,25 +462,27 @@ class FedEngine:
             plan = (None if ctx_plan is None else
                     {f: v[r - start:r - start + k]
                      for f, v in ctx_plan.items()})
-            ctx0 = self.make_ctx(data, weights=weights,
-                                 active_budget=active_budget, cohort=cohort,
-                                 population=population)
-            fn = self._get_chunk(k, n_open, n_r, state, ctx0, plan,
-                                 overlap=overlap)
+            # the chunk draws its keys and o_r on device: the host's part of
+            # sampling is the context and the chunk's program lookup
+            with obs.span("engine.sample", "engine", round=r):
+                ctx0 = self.make_ctx(data, weights=weights,
+                                     active_budget=active_budget,
+                                     cohort=cohort, population=population)
+                fn = self._get_chunk(k, n_open, n_r, state, ctx0, plan,
+                                     overlap=overlap)
             # the span covers dispatch through the chunk's one host sync
             # (device_get below) — all instrumentation sits OUTSIDE the
             # compiled scan, so the fused path stays bitwise identical and
             # keeps its one-sync-per-chunk discipline
-            t0 = time.perf_counter()
             with obs.span("engine.chunk", "engine", rounds=k, start_round=r,
                           overlap=overlap):
                 state, rng, ms = fn(state, ctx0, rng, plan)
                 self.last_metrics = {key: v[-1] for key, v in ms.items()}
                 # one host sync per chunk: the stacked per-round scalars land
                 # together instead of one float() device round-trip per round
-                scalars = jax.device_get({key: v for key, v in ms.items()
-                                          if jnp.ndim(v) == 1})
-            self._note_chunk_time(overlap, k, time.perf_counter() - t0)
+                with obs.span("engine.sync", "engine"):
+                    scalars = jax.device_get({key: v for key, v in ms.items()
+                                              if jnp.ndim(v) == 1})
             for i in range(k):
                 if (r + i + 1) % log_every != 0:
                     continue
@@ -500,28 +502,6 @@ class FedEngine:
             reg.counter("engine.rounds").inc(rounds)
             reg.counter("engine.chunks").inc(n_chunks)
         return state
-
-    def _note_chunk_time(self, overlap: bool, k: int, seconds: float) -> None:
-        """Host-side schedule telemetry, sampled only at chunk boundaries so
-        the compiled scan keeps its bitwise-parity and one-sync-per-chunk
-        contracts.  Each chunk contributes one per-round wallclock sample
-        to its schedule's bucket; once this engine has timed BOTH schedules
-        the ``engine.comm_hidden_us`` gauge reports the per-round time the
-        pipelined schedule hides (mean serialized - mean pipelined).  The
-        pipelined path additionally marks its in-flight exchange with a
-        ``wire.exchange`` instant — dispatch-side, since the all-gather
-        itself retires inside the compiled chunk."""
-        us = seconds * 1e6 / max(k, 1)
-        self._round_us.setdefault(bool(overlap), []).append(us)
-        if overlap:
-            obs.instant("wire.exchange", "wire", inflight=True, rounds=k)
-            obs.instant("overlap", "engine", rounds=k,
-                        per_round_us=round(us, 3))
-        reg = obs.current_registry()
-        ser, pipe = self._round_us.get(False), self._round_us.get(True)
-        if reg is not None and ser and pipe:
-            hidden = sum(ser) / len(ser) - sum(pipe) / len(pipe)
-            reg.gauge("engine.comm_hidden_us").set(round(hidden, 3))
 
     # -------------------------------------------------------- comm bytes ----
     def _payload_ctx(self, data) -> BatchCtx:

@@ -25,8 +25,9 @@ from ..models.api import model_logits
 from ..models.base import ModelConfig
 from .aggregation import era, sa, topk_compress, weighted_era, weighted_sa
 from .hierarchy import hierarchical_weighted_era, hierarchical_weighted_sa
-from .algorithms import (active_indices, gather_clients, masked_mean,
-                         scatter_clients, scatter_zeros, select_clients)
+from .algorithms import (AGGREGATE, DISTILL, PREDICT, UPDATE, active_indices,
+                         gather_clients, masked_mean, scatter_clients,
+                         scatter_zeros, select_clients)
 from .losses import (HIGHEST, distill_xent, pinned_sum, topk_distill_xent,
                      xent_int_labels)
 
@@ -72,14 +73,20 @@ def sgd_train_step(cfg: ModelConfig, params, batch, lr: float,
 def dsfl_client_loss(cfg: ModelConfig, params, private_batch, open_batch,
                      teacher, hp: LLMDsflHP):
     """CE on private tokens + gamma * KD on the open batch (Eqs. 1 + 10 fused
-    into one local step — the per-round client compute of DS-FL)."""
-    ce = lm_loss(cfg, params, private_batch, hp.aux_weight)
-    logits_o, _ = model_logits(cfg, params, open_batch)
-    if hp.topk is not None:
-        tv, ti = teacher
-        kd = topk_distill_xent(logits_o, tv, ti)
-    else:
-        kd = distill_xent(logits_o, teacher)
+    into one local step — the per-round client compute of DS-FL).  The CE
+    term is the round's update step and the KD term its distillation
+    (named scopes ``dsfl_update`` / ``dsfl_distill``, which the gradient
+    carries into the backward pass); the round has no server model, so no
+    ``dsfl_server_distill``."""
+    with jax.named_scope(UPDATE):
+        ce = lm_loss(cfg, params, private_batch, hp.aux_weight)
+    with jax.named_scope(DISTILL):
+        logits_o, _ = model_logits(cfg, params, open_batch)
+        if hp.topk is not None:
+            tv, ti = teacher
+            kd = topk_distill_xent(logits_o, tv, ti)
+        else:
+            kd = distill_xent(logits_o, teacher)
     return ce + hp.gamma * kd
 
 
@@ -111,8 +118,9 @@ def dsfl_client_step(cfg: ModelConfig, params, private_batch, open_batch,
 
         g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
         (grads, loss), _ = jax.lax.scan(body, (g0, jnp.float32(0.0)), mbs)
-    new = jax.tree.map(lambda p, g: p - (hp.lr * g).astype(p.dtype),
-                       params, grads)
+    with jax.named_scope(UPDATE):
+        new = jax.tree.map(lambda p, g: p - (hp.lr * g).astype(p.dtype),
+                           params, grads)
     return new, loss
 
 
@@ -133,6 +141,7 @@ def _is_sparse_round(K: int, hp: LLMDsflHP, weights, active_budget) -> bool:
             and active_budget < K and hp.topk is None)
 
 
+@jax.named_scope(PREDICT)
 def dsfl_exchange(cfg: ModelConfig, stacked_params, open_batch,
                   hp: LLMDsflHP, weights=None, mask=None,
                   active_budget=None):
@@ -210,23 +219,27 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
                                    active_budget)
     if hp.topk is not None:
         tv, ti = inflight
-        # shard-local densify: iota-compare instead of scatter (a scatter
-        # into a vocab-sharded output would replicate the dense tensor)
-        V = cfg.eff_vocab     # probs carry the padded (TP-divisible) vocab
-        iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 1, V), 4)
-        onehot = (iota == ti[..., None]).astype(jnp.float32)   # (Kc,B,S,k,V)
-        dense = jnp.einsum("cbsk,cbskv->cbsv", tv.astype(jnp.float32), onehot,
-                           precision=HIGHEST)
-        dense = constrain(dense, None, "batch", None, "model")
-        teacher = aggregate_teacher(dense, hp, weights)
-        teacher = constrain(teacher, "batch", None, "model")
+        with jax.named_scope(AGGREGATE):
+            # shard-local densify: iota-compare instead of scatter (a
+            # scatter into a vocab-sharded output would replicate the dense
+            # tensor)
+            V = cfg.eff_vocab   # probs carry the padded (TP-divisible) vocab
+            iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, 1, V), 4)
+            # (Kc, B, S, k, V)
+            onehot = (iota == ti[..., None]).astype(jnp.float32)
+            dense = jnp.einsum("cbsk,cbskv->cbsv", tv.astype(jnp.float32),
+                               onehot, precision=HIGHEST)
+            dense = constrain(dense, None, "batch", None, "model")
+            teacher = aggregate_teacher(dense, hp, weights)
+            teacher = constrain(teacher, "batch", None, "model")
         # the exchange leg is compressed; the pod-local distillation uses the
         # dense (vocab-sharded) teacher — no top_k over a sharded axis
         import dataclasses
         hp = dataclasses.replace(hp, topk=None)
     else:
         (probs,) = inflight
-        teacher = aggregate_teacher(probs, hp, weights)
+        with jax.named_scope(AGGREGATE):
+            teacher = aggregate_teacher(probs, hp, weights)
 
     new_params, losses = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
@@ -234,7 +247,8 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
     if weights is not None:
         # absent clients neither update nor average into the loss
         m = (weights if mask is None else mask).astype(jnp.float32) > 0
-        new_params = select_clients(m, new_params, stacked_params)
+        with jax.named_scope(UPDATE):
+            new_params = select_clients(m, new_params, stacked_params)
         return new_params, masked_mean(losses, m)
     return new_params, jnp.mean(losses)
 
@@ -293,19 +307,24 @@ def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
     carried in ``inflight``, so the exchange buffers stay O(m)."""
     K = jax.tree.leaves(stacked_params)[0].shape[0]
     act = weights if mask is None else mask
-    idx = active_indices(act, active_budget)
-    act_m = jnp.take(act, idx, axis=0)
-    params_m = gather_clients(stacked_params, idx)
-    batches_m = gather_clients(private_batches, idx)
+    with jax.named_scope(UPDATE):
+        idx = active_indices(act, active_budget)
+        act_m = jnp.take(act, idx, axis=0)
+        params_m = gather_clients(stacked_params, idx)
+        batches_m = gather_clients(private_batches, idx)
 
     (probs_m,) = inflight                                   # (m, B, S, V)
-    teacher = aggregate_teacher(scatter_zeros(probs_m, K, idx), hp, weights)
+    with jax.named_scope(AGGREGATE):
+        teacher = aggregate_teacher(scatter_zeros(probs_m, K, idx), hp,
+                                    weights)
 
     new_m, losses_m = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
     )(params_m, batches_m)
-    new_m = select_clients(act_m.astype(jnp.float32) > 0, new_m, params_m)
-    new_params = scatter_clients(new_m, stacked_params, idx)
+    with jax.named_scope(UPDATE):
+        new_m = select_clients(act_m.astype(jnp.float32) > 0, new_m,
+                               params_m)
+        new_params = scatter_clients(new_m, stacked_params, idx)
     losses = scatter_zeros(losses_m, K, idx)
     return new_params, masked_mean(losses, act.astype(jnp.float32) > 0)
 

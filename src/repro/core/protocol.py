@@ -138,10 +138,14 @@ class DSFLEngine:
         return wk, sk, wg, sg
 
 
+EVAL = "fed_eval"    # `jax.named_scope` of the test-set forward
+
+
 def make_eval_fn(apply_fn, x_test, y_test, batch: int = 1000):
     @jax.jit
     def _logits(w, s):
-        logits, _ = apply_fn(w, s, x_test, False)
+        with jax.named_scope(EVAL):
+            logits, _ = apply_fn(w, s, x_test, False)
         return logits
 
     def eval_fn(w, s):

@@ -6,7 +6,9 @@ the one-host-sync-per-chunk discipline are untouched — pinned by
 ``tests/test_obs.py``):
 
 * `Tracer` (`trace.py`) — structured JSONL span/event records with
-  monotonic host timestamps, pid/tid, and nesting via context managers.
+  monotonic host timestamps, pid/tid, and nesting via context managers;
+  ``annotate(True)`` also opens each span as a `jax.profiler`
+  annotation, so the spans land on the device trace's clock.
   `perfetto.py` exports a trace to Chrome/Perfetto ``trace_event`` JSON so
   a whole run — engine chunks, cohort slab gather/scatter, wire
   measurement, serve prefill/decode, weight hot-swaps, XLA compiles —
@@ -33,13 +35,13 @@ from .jit_watch import JitCacheWatch, engine_compile_counts  # noqa: F401
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,  # noqa
                       percentile, percentiles)
 from .provenance import RunProvenance  # noqa: F401
-from .trace import (Tracer, current_registry, enabled, event,  # noqa
-                    install, install_registry, instant, span, start, stop,
-                    trace_to)
+from .trace import (Tracer, annotate, current_registry, enabled,  # noqa
+                    event, install, install_registry, instant, span, start,
+                    stop, trace_to)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "JitCacheWatch", "MetricsRegistry",
-    "RunProvenance", "Tracer", "current_registry", "enabled",
+    "RunProvenance", "Tracer", "annotate", "current_registry", "enabled",
     "engine_compile_counts", "event", "install", "install_registry",
     "instant", "percentile", "percentiles", "span", "start", "stop",
     "trace_to",

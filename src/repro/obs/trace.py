@@ -12,14 +12,21 @@ A `Tracer` appends one JSON object per line to a file:
   exactly how Perfetto renders same-tid "X" events).
 * instants: same shape, no ``dur_us``.
 
-Nothing here touches jax: spans measure *host-visible* phases (a jitted
-call's span covers dispatch-to-sync, which is the number serving/training
-actually waits on).  Instrumented libraries call the module-level
+Spans measure *host-visible* phases (a jitted call's span covers
+dispatch-to-sync, which is the number serving/training actually waits
+on).  Instrumented libraries call the module-level
 ``span``/``event``/``instant`` helpers, which hit the process-global
 tracer installed by ``start``/``install``/``trace_to`` — with none
 installed they return a shared no-op context manager: one global read,
 zero allocation, no timestamps taken (the zero-overhead-when-disabled
 contract, parity-pinned in ``tests/test_obs.py``).
+
+``annotate(True)`` puts the same spans on the profiler's clock: while it
+is on, ``span(name)`` also opens a `jax.profiler.TraceAnnotation(name)`,
+which a running `jax.profiler` trace records on its ``/host:CPU`` plane
+beside the device ops (instants stay JSONL-only).  It works with or
+without a JSONL tracer installed; jax is imported only when it is
+switched on.
 """
 from __future__ import annotations
 
@@ -150,9 +157,47 @@ class Tracer:
                     **({"args": args} if args else {})})
 
 
+class _AnnotatedSpan:
+    """A span that is also a profiler annotation (``annotate(True)``);
+    ``span`` is the JSONL span, the shared no-op with no tracer."""
+    __slots__ = ("span", "ann")
+
+    def __init__(self, span, name: str):
+        self.span = span
+        self.ann = _ANNOTATION(name)
+
+    def set(self, **args):
+        self.span.set(**args)
+        return self
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.span.__exit__(*exc)
+        self.ann.__exit__(*exc)
+        return False
+
+
 # ------------------------------------------------------------ global plane ---
 _TRACER: Optional[Tracer] = None
 _REGISTRY = None                      # Optional[MetricsRegistry]
+_ANNOTATION = None                    # jax.profiler.TraceAnnotation when on
+# what ``span`` builds: None while neither a tracer nor annotation is on,
+# so the disabled path stays one global read
+_MAKE_SPAN = None
+
+
+def _rebind() -> None:
+    global _MAKE_SPAN
+    t = _TRACER
+    if _ANNOTATION is None:
+        _MAKE_SPAN = None if t is None else t.span
+    else:
+        _MAKE_SPAN = lambda name, cat, **args: _AnnotatedSpan(
+            _NULL_SPAN if t is None else t.span(name, cat, **args), name)
 
 
 def install(tracer: Optional[Tracer]) -> Optional[Tracer]:
@@ -160,6 +205,21 @@ def install(tracer: Optional[Tracer]) -> Optional[Tracer]:
     returns the previous one so callers can restore it."""
     global _TRACER
     prev, _TRACER = _TRACER, tracer
+    _rebind()
+    return prev
+
+
+def annotate(on: bool) -> bool:
+    """Also open a `jax.profiler.TraceAnnotation` for every ``span`` while
+    ``on`` (see the module docstring); returns the previous setting."""
+    global _ANNOTATION
+    prev = _ANNOTATION is not None
+    if on:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    else:
+        _ANNOTATION = None
+    _rebind()
     return prev
 
 
@@ -180,6 +240,7 @@ def stop() -> None:
     if _TRACER is not None:
         _TRACER.close()
         _TRACER = None
+        _rebind()
 
 
 def enabled() -> bool:
@@ -187,10 +248,10 @@ def enabled() -> bool:
 
 
 def span(name: str, cat: str = "app", **args):
-    """The library-side entry point: a real span when tracing is on, the
-    shared no-op otherwise."""
-    t = _TRACER
-    return _NULL_SPAN if t is None else t.span(name, cat, **args)
+    """The library-side entry point: a real span when tracing or
+    annotation is on, the shared no-op otherwise."""
+    make = _MAKE_SPAN
+    return _NULL_SPAN if make is None else make(name, cat, **args)
 
 
 def instant(name: str, cat: str = "app", **args) -> None:

@@ -448,18 +448,19 @@ class ServeEngine:
         self.n_dispatches += 1
         done_before = len(self.completed)
         used = 0
-        for j in range(d):
-            if all(t is None for t in self.tasks):
-                break                   # the d=1 loop would have stopped
-            used += 1
-            t_j = now + j * step_dt     # true virtual time of sub-step j
-            for i, task in enumerate(self.tasks):
-                if task is None:
-                    continue
-                if task.pending:
-                    task.pending.pop(0)     # forced: prediction superseded
-                else:
-                    self._emit(i, int(mat[j, i]), t_j)
+        with obs.span("serve.replay", "serve", chunk=d):
+            for j in range(d):
+                if all(t is None for t in self.tasks):
+                    break               # the d=1 loop would have stopped
+                used += 1
+                t_j = now + j * step_dt     # true virtual time of sub-step j
+                for i, task in enumerate(self.tasks):
+                    if task is None:
+                        continue
+                    if task.pending:
+                        task.pending.pop(0)  # forced: prediction superseded
+                    else:
+                        self._emit(i, int(mat[j, i]), t_j)
         # the device chained tok/pos through the same masking the replay
         # just applied (finished lanes frozen), so these ARE the d=1 state
         self.tok, self.pos = tok, pos

@@ -214,10 +214,10 @@ def test_llm_overlap_parity_under_mesh(task, stacked):
 
 
 def test_overlap_telemetry_is_host_side_and_published(tmp_path):
-    """Satellite pin: the pipelined path emits `wire.exchange`/`overlap`
-    instants (at chunk boundaries — never inside the compiled chunk) and,
-    once both schedules have been timed, the `engine.comm_hidden_us`
-    gauge; instrumentation must not change a bit of the history."""
+    """Satellite pin: the pipelined path emits the same `engine.chunk`
+    spans as the sequential one — one per chunk, at chunk boundaries,
+    never inside the compiled chunk — and instrumentation must not change
+    a bit of the history or the state."""
     import json
 
     from repro import obs
@@ -231,28 +231,30 @@ def test_overlap_telemetry_is_host_side_and_published(tmp_path):
     itask = build_image_task(seed=0, K=4, n_private=160, n_open=80,
                              n_test=40, distribution="non_iid")
 
-    def go(traced):
+    def go(overlap):
         eng = FedEngine(DSFLAlgorithm(apply_tiny_mlp, hp))
-        for overlap in (False, True):
-            state = eng.init(init_tiny_mlp, itask)
-            eng.run(state, itask, rounds=4, chunk_rounds=2, overlap=overlap)
-        if traced:
-            return eng.history, obs.current_registry()
-        return eng.history, None
+        state = eng.run(eng.init(init_tiny_mlp, itask), itask, rounds=4,
+                        chunk_rounds=2, overlap=overlap)
+        return eng.history, state
 
-    plain, _ = go(traced=False)
-    path = os.path.join(tmp_path, "overlap.jsonl")
-    with obs.trace_to(str(path)):
-        prev = obs.install_registry(obs.MetricsRegistry())
-        try:
-            traced, reg = go(traced=True)
-            hidden = reg.gauge("engine.comm_hidden_us").value
-        finally:
-            obs.install_registry(prev)
-    assert traced == plain                     # host-side only: same bits
-    assert hidden is not None                  # both schedules timed
-    names = [json.loads(l).get("name") for l in open(path) if l.strip()]
-    assert "wire.exchange" in names and "overlap" in names
+    plain, s_plain = go(overlap=True)
+    chunks = {}
+    for overlap in (False, True):
+        path = os.path.join(tmp_path, f"overlap{int(overlap)}.jsonl")
+        with obs.trace_to(str(path)):
+            prev = obs.install_registry(obs.MetricsRegistry())
+            try:
+                traced, s_traced = go(overlap)
+            finally:
+                obs.install_registry(prev)
+        recs = [json.loads(l) for l in open(path) if l.strip()]
+        chunks[overlap] = [r["args"] for r in recs
+                           if r.get("name") == "engine.chunk"]
+        assert traced == plain                 # host-side only: same bits
+        _states_equal(s_traced, s_plain)
+    assert len(chunks[True]) == len(chunks[False]) == 2
+    assert [c["overlap"] for c in chunks[True]] == [True, True]
+    assert [c["start_round"] for c in chunks[True]] == [0, 2]
 
 
 def test_llm_round_equals_finish_of_start(task, stacked):
