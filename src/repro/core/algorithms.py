@@ -43,7 +43,8 @@ from ..optim import optimizers as opt_lib
 from . import fd as fd_lib
 from .aggregation import (aggregate, participation_weights, weighted_era,
                           weighted_sa)
-from .client import LocalSpec, local_distill, local_update, predict_probs
+from .client import (LocalSpec, local_distill, local_update, over_clients,
+                     predict_probs)
 from .fedavg import weighted_average
 from .hierarchy import hierarchical_weighted_era, hierarchical_weighted_sa
 from .losses import entropy, pinned_mean, pinned_sum
@@ -414,10 +415,10 @@ class DSFLAlgorithm:
         # 1. Update (always computed for the full stack — a fused where keeps
         # absent clients' state; no per-client Python loop, shards cleanly)
         with jax.named_scope(UPDATE):
-            wk_n, sk_n, ouk_n, up_loss = jax.vmap(
+            wk_n, sk_n, ouk_n, up_loss = over_clients(
                 lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk,
-                                                         yk, rk)
-            )(wk, sk, ouk, ctx.x, ctx.y, client_keys(r1, ctx, K))
+                                                         yk, rk),
+                wk, sk, ouk, ctx.x, ctx.y, client_keys(r1, ctx, K))
             if masked:
                 wk, sk, ouk = select_clients(ctx.mask, (wk_n, sk_n, ouk_n),
                                              (wk, sk, ouk))
@@ -485,10 +486,10 @@ class DSFLAlgorithm:
 
         # 6. Distillation (clients, Eq. 10; absent clients keep their state)
         with jax.named_scope(DISTILL):
-            wk_n, sk_n, odk_n, d_loss = jax.vmap(
+            wk_n, sk_n, odk_n, d_loss = over_clients(
                 lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
-                                                  global_logit, rk)
-            )(wk, sk, odk, client_keys(r2, ctx, K))
+                                                  global_logit, rk),
+                wk, sk, odk, client_keys(r2, ctx, K))
             if masked:
                 wk, sk, odk = select_clients(ctx.mask, (wk_n, sk_n, odk_n),
                                              (wk, sk, odk))
@@ -522,8 +523,8 @@ class DSFLAlgorithm:
 
     def _sparse_start(self, state: RoundState, ctx: BatchCtx, rng, m: int):
         """Participation-sparse start leg: gather the <= m active lanes of
-        the client stack and run "1. Update" / "2. Prediction" vmapped over
-        only the (m, ...) slice — ~K/m less client compute and activation
+        the client stack and run "1. Update" / "2. Prediction" over only
+        the (m, ...) slice — ~K/m less client compute and activation
         memory, **bitwise identical** to the dense masked round (pinned by
         tests/test_engine_scan.py): per-client math sees the same inputs
         and the same per-client keys, and padding lanes carry exactly zero
@@ -548,11 +549,11 @@ class DSFLAlgorithm:
             mask_m = jnp.take(ctx.mask, idx, axis=0)
             x_m, y_m = gather_clients((ctx.x, ctx.y), idx)
             wk_m, sk_m, ouk_m = gather_clients((wk, sk, ouk), idx)
-            wk_n, sk_n, ouk_n, up_loss = jax.vmap(
+            wk_n, sk_n, ouk_n, up_loss = over_clients(
                 lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk,
-                                                         yk, rk)
-            )(wk_m, sk_m, ouk_m, x_m, y_m,
-              jnp.take(client_keys(r1, ctx, K), idx, axis=0))
+                                                         yk, rk),
+                wk_m, sk_m, ouk_m, x_m, y_m,
+                jnp.take(client_keys(r1, ctx, K), idx, axis=0))
             wk_m, sk_m, ouk_m = select_clients(mask_m, (wk_n, sk_n, ouk_n),
                                                (wk_m, sk_m, ouk_m))
 
@@ -593,11 +594,11 @@ class DSFLAlgorithm:
 
         # 6. Distillation (clients) on the gathered lanes
         with jax.named_scope(DISTILL):
-            wk_n, sk_n, odk_n, d_loss = jax.vmap(
+            wk_n, sk_n, odk_n, d_loss = over_clients(
                 lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
-                                                  global_logit, rk)
-            )(wk_m, sk_m, odk_m,
-              jnp.take(client_keys(r2, ctx, K), idx, axis=0))
+                                                  global_logit, rk),
+                wk_m, sk_m, odk_m,
+                jnp.take(client_keys(r2, ctx, K), idx, axis=0))
             wk_m, sk_m, odk_m = select_clients(mask_m, (wk_n, sk_n, odk_n),
                                                (wk_m, sk_m, odk_m))
 

@@ -3,10 +3,11 @@
 A "client model" is any functional pair ``apply(params, state, x, train)``
 -> ``(logits, new_state)`` (the smallnets API; LLM wrappers adapt to it).
 All loops are pure ``lax.scan`` so a whole federated round jits as one XLA
-program and ``jax.vmap`` lifts them over the client axis.
+program, and `over_clients` lifts them over the client axis.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -29,6 +30,71 @@ def _epoch_perm(key, n_items: int, batch_size: int) -> jax.Array:
     nb = n_items // batch_size
     return jax.random.permutation(key, n_items)[: nb * batch_size
                                                 ].reshape(nb, batch_size)
+
+
+# One client's step maps (runs clients one after another) when its largest
+# contraction has at least this many multiply-adds.  TPU v5e, DS-FL round,
+# lax.map against vmap at K=4..100 (benchmarks/client_loop_bench.py):
+# steps up to 9.2e7 (tiny_mlp, the IMDb LSTM, 16-pixel CNNs) run as fast or
+# faster under vmap, by up to 1.6 times; steps from 5.1e8 up (the paper's
+# two CNNs and its Reuters DNN) run 1.07-3.6 times faster under map.
+MAP_MIN_MACS = 1 << 27
+
+
+def _largest_contraction(jaxpr) -> int:
+    """Multiply-adds of the largest ``dot_general`` or
+    ``conv_general_dilated`` in ``jaxpr`` or in a jaxpr in its equations'
+    parameters (scan and while bodies, cond branches, pjit and
+    custom-derivative calls)."""
+    most = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (lhs_c, _), _ = eqn.params["dimension_numbers"]
+            lhs = eqn.invars[0].aval.shape
+            per_out = math.prod(lhs[d] for d in lhs_c)
+        elif eqn.primitive.name == "conv_general_dilated":
+            rhs = eqn.invars[1].aval     # its out-feature dim, then the rest
+            per_out = rhs.size // rhs.shape[
+                eqn.params["dimension_numbers"].rhs_spec[0]]
+        else:
+            per_out = 0
+        if per_out:
+            most = max(most, eqn.outvars[0].aval.size * per_out)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)      # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    most = max(most, _largest_contraction(sub))
+    return most
+
+
+def loop_path(fn: Callable, *args) -> str:
+    """``"map"`` if one client's ``fn`` (its arrays: ``args`` without their
+    leading client axis) traces to a contraction of at least
+    `MAP_MIN_MACS` multiply-adds, else ``"vmap"``: the lowering
+    `over_clients` gives ``fn``."""
+    one = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
+                       args)
+    big = _largest_contraction(jax.make_jaxpr(fn)(*one).jaxpr)
+    return "map" if big >= MAP_MIN_MACS else "vmap"
+
+
+def over_clients(fn: Callable, *args):
+    """``fn`` (one client's arrays -> one client's results) over the leading
+    client axis of ``args``, results stacked along it.
+
+    ``jax.vmap`` batches every client's step into one, which keeps a small
+    step's ops busy but multiplies a large step's working set by K and
+    folds the client axis of a convolution into ``feature_group_count``
+    (one group per client), which the MXU runs far below a plain
+    convolution.  So a step large enough to fill the chip alone runs one
+    client at a time under ``lax.map`` (each client's whole epoch scan
+    inside, every convolution ungrouped), and a smaller one under
+    ``jax.vmap`` (`loop_path`).  Either way each client sees the same
+    inputs and keys."""
+    if loop_path(fn, *args) == "map":
+        return jax.lax.map(lambda a: fn(*a), args)
+    return jax.vmap(fn)(*args)
 
 
 def local_update(spec: LocalSpec, params, state, opt_state, x, y, rng,

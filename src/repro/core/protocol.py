@@ -4,7 +4,8 @@ is a mean over that axis (on a TPU mesh this axis is sharded over pods and
 the mean lowers to the logit all-reduce — see core/llm_dsfl.py).
 
 Round structure (Fig. 1 (c)):
-  1. Update       - local SGD on private data (vmap of client.local_update)
+  1. Update       - local SGD on private data (client.local_update over
+                   the client axis, client.over_clients)
   2. Prediction   - local probs on the shared open-batch o_r (Eq. 9)
   3-5. Upload/Aggregate/Broadcast - aggregation.aggregate (SA / ERA)
   6. Distillation - clients AND the server global model train on (D^{o_r}, T̂)
@@ -19,7 +20,8 @@ import jax.numpy as jnp
 
 from ..optim import optimizers as opt_lib
 from .aggregation import aggregate
-from .client import LocalSpec, local_distill, local_update, predict_probs
+from .client import (LocalSpec, local_distill, local_update, over_clients,
+                     predict_probs)
 from .losses import accuracy, entropy
 
 
@@ -57,9 +59,10 @@ def make_dsfl_round(apply_fn: Callable, hp: DSFLConfig,
         xo = jnp.take(open_x, o_idx, axis=0)
 
         # 1. Update
-        wk, sk, ouk, up_loss = jax.vmap(
-            lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk, yk, rk)
-        )(wk, sk, ouk, x, y, jax.random.split(r1, K))
+        wk, sk, ouk, up_loss = over_clients(
+            lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk, yk,
+                                                     rk),
+            wk, sk, ouk, x, y, jax.random.split(r1, K))
 
         # 2. Prediction (local logits on o_r)
         probs = jax.vmap(lambda w, s: predict_probs(apply_fn, w, s, xo))(wk, sk)
@@ -72,10 +75,10 @@ def make_dsfl_round(apply_fn: Callable, hp: DSFLConfig,
         g_entropy = jnp.mean(entropy(global_logit))
 
         # 6. Distillation (clients, Eq. 10)
-        wk, sk, odk, d_loss = jax.vmap(
+        wk, sk, odk, d_loss = over_clients(
             lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
-                                              global_logit, rk)
-        )(wk, sk, odk, jax.random.split(r2, K))
+                                              global_logit, rk),
+            wk, sk, odk, jax.random.split(r2, K))
 
         # 6'. server global model (Eq. 11) — own key, so the server's distill
         # minibatch permutations are independent of the clients' (r2)
