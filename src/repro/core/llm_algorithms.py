@@ -98,9 +98,17 @@ def _shardings(cfg: ModelConfig, mesh, state: RoundState, ctx: BatchCtx,
 class LLMDSFLAlgorithm:
     """DS-FL at pod scale on the unified API: each federated client is one
     pod; the round's only cross-pod collective is the open-batch logit
-    exchange (all-gather of top-k pairs under ``hp.topk``)."""
+    exchange (all-gather of top-k pairs under ``hp.topk``).
+
+    ``probe_rows`` (flat indices, sequence * S + position, of tokens of
+    the round's open batch o_r) makes every round also report what the
+    exchange delivered there: the metric ``client_mean``, the clients'
+    float32 mean prediction ERA sharpened, (n, V), on the engine's
+    ``last_metrics``.  None (the default) reports nothing and leaves the
+    round's program as it was."""
     cfg: ModelConfig
     hp: LLMDsflHP
+    probe_rows: tuple | None = None
 
     name = "llm_dsfl"
     uses_open = True
@@ -116,12 +124,18 @@ class LLMDSFLAlgorithm:
     def round(self, state: RoundState, ctx: BatchCtx, rng):
         del rng   # dsfl_round_step is deterministic given the batches
         open_b = _take_open(ctx.open_x, ctx.o_idx)
-        new, loss = dsfl_round_step(
+        return self._result(*dsfl_round_step(
             self.cfg, state.clients.params, ctx.x, open_b, self.hp,
             weights=_participation(ctx, self.hp.staleness_decay),
             mask=ctx.mask if present(ctx.mask) else None,
-            active_budget=ctx.active_budget)
-        return RoundState(clients=ClientState(params=new)), {"loss": loss}
+            active_budget=ctx.active_budget, probe_rows=self.probe_rows))
+
+    @staticmethod
+    def _result(new, loss, *probe):
+        metrics = {"loss": loss}
+        if probe:
+            metrics["client_mean"] = probe[0]
+        return RoundState(clients=ClientState(params=new)), metrics
 
     # -- pipelined round halves (engine `overlap=True` path) ----------------
     # round == round_finish(state, ctx, round_start(state, ctx, rng), rng)
@@ -146,12 +160,11 @@ class LLMDSFLAlgorithm:
         touches ``inflight`` — the slack the wire hides behind)."""
         del rng
         open_b = _take_open(ctx.open_x, ctx.o_idx)
-        new, loss = dsfl_round_finish(
+        return self._result(*dsfl_round_finish(
             self.cfg, state.clients.params, ctx.x, open_b, inflight, self.hp,
             weights=_participation(ctx, self.hp.staleness_decay),
             mask=ctx.mask if present(ctx.mask) else None,
-            active_budget=ctx.active_budget)
-        return RoundState(clients=ClientState(params=new)), {"loss": loss}
+            active_budget=ctx.active_budget, probe_rows=self.probe_rows))
 
     def upload_payload(self, state: RoundState, ctx: BatchCtx):
         """One client's upload: per-token class distributions on o_r —

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from ..models.api import model_logits
 from ..models.base import ModelConfig
-from .aggregation import era, sa, topk_compress, weighted_era, weighted_sa
+from .aggregation import sa, topk_compress, weighted_sa
 from .hierarchy import hierarchical_weighted_era, hierarchical_weighted_sa
 from .algorithms import (AGGREGATE, DISTILL, PREDICT, UPDATE, active_indices,
                          gather_clients, masked_mean, scatter_clients,
@@ -204,19 +204,23 @@ def dsfl_exchange(cfg: ModelConfig, stacked_params, open_batch,
 
 def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
                       open_batch, inflight, hp: LLMDsflHP, weights=None,
-                      mask=None, active_budget=None):
+                      mask=None, active_budget=None, probe_rows=None):
     """The COMPUTE leg of a DS-FL round: "4. Aggregation" + "5. Broadcast"
     + the hybrid CE+KD client step, consuming the exchange buffers
     `dsfl_exchange` put in flight.  The private-batch CE branch of
     ``dsfl_client_step`` has no data dependency on ``inflight`` — only
     the KD term and the open-branch backward seed do — which is the slack
-    the pipelined schedule hides the wire behind."""
+    the pipelined schedule hides the wire behind.
+
+    ``probe_rows`` (flat open-batch token indices, sequence * S +
+    position) adds a third result: the exchanged aggregate the teacher
+    was sharpened from (`client_mean`, float32) at those tokens, (n, V)."""
     from ..models.shardctx import constrain
     K = jax.tree.leaves(stacked_params)[0].shape[0]
     if _is_sparse_round(K, hp, weights, active_budget):
         return _dsfl_finish_sparse(cfg, stacked_params, private_batches,
                                    open_batch, inflight, hp, weights, mask,
-                                   active_budget)
+                                   active_budget, probe_rows)
     if hp.topk is not None:
         tv, ti = inflight
         with jax.named_scope(AGGREGATE):
@@ -230,7 +234,7 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
             dense = jnp.einsum("cbsk,cbskv->cbsv", tv.astype(jnp.float32),
                                onehot, precision=HIGHEST)
             dense = constrain(dense, None, "batch", None, "model")
-            teacher = aggregate_teacher(dense, hp, weights)
+            teacher, mean = _aggregate(dense, hp, weights)
             teacher = constrain(teacher, "batch", None, "model")
         # the exchange leg is compressed; the pod-local distillation uses the
         # dense (vocab-sharded) teacher — no top_k over a sharded axis
@@ -239,7 +243,7 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
     else:
         (probs,) = inflight
         with jax.named_scope(AGGREGATE):
-            teacher = aggregate_teacher(probs, hp, weights)
+            teacher, mean = _aggregate(probs, hp, weights)
 
     new_params, losses = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
@@ -249,13 +253,15 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
         m = (weights if mask is None else mask).astype(jnp.float32) > 0
         with jax.named_scope(UPDATE):
             new_params = select_clients(m, new_params, stacked_params)
-        return new_params, masked_mean(losses, m)
-    return new_params, jnp.mean(losses)
+        loss = masked_mean(losses, m)
+    else:
+        loss = jnp.mean(losses)
+    return _with_probe(new_params, loss, mean, probe_rows)
 
 
 def dsfl_round_step(cfg: ModelConfig, stacked_params, private_batches,
                     open_batch, hp: LLMDsflHP, weights=None, mask=None,
-                    active_budget=None):
+                    active_budget=None, probe_rows=None):
     """One full DS-FL round over the pod-sharded client axis: the
     composition ``dsfl_round_finish(..., dsfl_exchange(...))``.
 
@@ -268,6 +274,9 @@ def dsfl_round_step(cfg: ModelConfig, stacked_params, private_batches,
     paper's upload leg): the cross-pod traffic becomes an all-gather of
     (value, index) pairs — k*(4+4) bytes/token instead of V*2 — and the
     dense densify+ERA runs pod-locally on the gathered pairs.
+
+    ``probe_rows`` adds the exchanged aggregate at those open-batch tokens
+    as a third result (see `dsfl_round_finish`).
 
     ``weights`` (K,), when given, turns the exchange into the sim layer's
     partial-participation round: zero-weight (absent) clients contribute
@@ -291,12 +300,26 @@ def dsfl_round_step(cfg: ModelConfig, stacked_params, private_batches,
                              active_budget=active_budget)
     return dsfl_round_finish(cfg, stacked_params, private_batches,
                              open_batch, inflight, hp, weights=weights,
-                             mask=mask, active_budget=active_budget)
+                             mask=mask, active_budget=active_budget,
+                             probe_rows=probe_rows)
+
+
+def _with_probe(new_params, loss, mean, probe_rows):
+    """The round's results, with the aggregate's ``probe_rows`` rows when
+    asked for."""
+    if probe_rows is None:
+        return new_params, loss
+    if mean is None:
+        raise ValueError("probe_rows needs the one-level aggregation "
+                         "(agg_edges=1): the edge tree never forms the "
+                         "whole client mean")
+    rows = jnp.asarray(probe_rows, jnp.int32)
+    return new_params, loss, mean.reshape(-1, mean.shape[-1])[rows]
 
 
 def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
                         open_batch, inflight, hp: LLMDsflHP, weights, mask,
-                        active_budget: int):
+                        active_budget: int, probe_rows=None):
     """Participation-sparse finish leg: same gather -> compute -> scatter
     plane as `algorithms.DSFLAlgorithm._sparse_round`, along the
     pod-sharded client axis.  Bitwise identical to the dense ``weights=``
@@ -315,8 +338,8 @@ def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
 
     (probs_m,) = inflight                                   # (m, B, S, V)
     with jax.named_scope(AGGREGATE):
-        teacher = aggregate_teacher(scatter_zeros(probs_m, K, idx), hp,
-                                    weights)
+        teacher, mean = _aggregate(scatter_zeros(probs_m, K, idx), hp,
+                                   weights)
 
     new_m, losses_m = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
@@ -326,7 +349,16 @@ def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
                                params_m)
         new_params = scatter_clients(new_m, stacked_params, idx)
     losses = scatter_zeros(losses_m, K, idx)
-    return new_params, masked_mean(losses, act.astype(jnp.float32) > 0)
+    return _with_probe(new_params,
+                       masked_mean(losses, act.astype(jnp.float32) > 0),
+                       mean, probe_rows)
+
+
+def client_mean(probs, weights):
+    """The exchanged aggregate: the clients' float32 mean prediction (SA,
+    Eq. 16), weighted when the sim supplies ``weights``.  The mean over
+    the pod-sharded client axis is the round's cross-pod all-reduce."""
+    return sa(probs) if weights is None else weighted_sa(probs, weights)
 
 
 def aggregate_teacher(probs, hp: LLMDsflHP, weights):
@@ -338,6 +370,13 @@ def aggregate_teacher(probs, hp: LLMDsflHP, weights):
     carries n_edges (n, S, V) partials instead of K upload stacks.  The
     parity/tolerance contract is `core.hierarchy`'s: bitwise at one edge,
     pinned tolerance deeper."""
+    return _aggregate(probs, hp, weights)[0]
+
+
+def _aggregate(probs, hp: LLMDsflHP, weights):
+    """`aggregate_teacher`'s teacher (bfloat16) and the `client_mean` it
+    was formed from (None through the edge tree).  ERA is
+    softmax(mean / T), as `aggregation.era`/`weighted_era` compute it."""
     if hp.agg_edges > 1:
         w = (jnp.ones((probs.shape[0],), jnp.float32)
              if weights is None else weights)
@@ -345,13 +384,11 @@ def aggregate_teacher(probs, hp: LLMDsflHP, weights):
                                          hp.agg_edges)
                if hp.aggregation == "era"
                else hierarchical_weighted_sa(probs, w, hp.agg_edges))
-    elif weights is None:
-        agg = era(probs, hp.temperature) if hp.aggregation == "era" \
-            else sa(probs)
-    else:
-        agg = (weighted_era(probs, weights, hp.temperature)
-               if hp.aggregation == "era" else weighted_sa(probs, weights))
-    return agg.astype(jnp.bfloat16)
+        return agg.astype(jnp.bfloat16), None
+    mean = client_mean(probs, weights)
+    agg = (jax.nn.softmax(mean / hp.temperature, axis=-1)
+           if hp.aggregation == "era" else mean)
+    return agg.astype(jnp.bfloat16), mean
 
 
 def fedavg_round_step(cfg: ModelConfig, stacked_params, private_batches,

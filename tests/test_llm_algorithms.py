@@ -16,7 +16,8 @@ from repro.core.comm import CommModel
 from repro.core.engine import FedEngine
 from repro.core.llm_algorithms import (LLMDSFLAlgorithm, LLMFedAvgAlgorithm,
                                        LLMFedAvgHP)
-from repro.core.llm_dsfl import (LLMDsflHP, dsfl_round_step,
+from repro.core.aggregation import sa
+from repro.core.llm_dsfl import (LLMDsflHP, dsfl_exchange, dsfl_round_step,
                                  fedavg_round_step)
 from repro.data.pipeline import build_lm_task
 from repro.models.api import model_init
@@ -150,6 +151,58 @@ def test_llm_dsfl_sharded_engine_chunked_scan_parity(task, stacked):
     if pod_size > 1:
         sh = jax.tree.leaves(o2.clients.params)[0].sharding
         assert "pod" in sh.spec
+
+
+# ------------------------------------------------------- exchange probe ----
+PROBE = (0, 5, S + 3, 3 * S - 1, B * S - 1)
+
+
+@pytest.mark.parametrize("path", ["loop", "scan_overlap"])
+def test_llm_dsfl_probe_reports_the_exchanged_mean(task, stacked, path):
+    """``probe_rows`` reports the clients' float32 mean at those o_r tokens
+    (what the exchange delivered, before ERA) and changes nothing else:
+    parameters and losses bitwise as without it, on the per-round loop
+    and on the pipelined scan."""
+    rounds, kw = ((1, {}) if path == "loop"
+                  else (2, dict(chunk_rounds=2, overlap=True)))
+    hp = LLMDsflHP(lr=5e-3, rounds=rounds, seed=0, open_batch=B)
+
+    def go(probe):
+        algo = LLMDSFLAlgorithm(CFG, hp, probe_rows=probe)
+        eng = FedEngine(algo)
+        return eng, eng.run(algo.init_from(stacked), task, **kw)
+
+    plain, out = go(None)
+    probed, out_p = go(PROBE)
+    assert probed.history == plain.history
+    for a, b in zip(jax.tree.leaves(out.clients.params),
+                    jax.tree.leaves(out_p.clients.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert "client_mean" not in plain.last_metrics
+    got = np.asarray(probed.last_metrics["client_mean"])
+    # the last round's exchange, recomputed from its input parameters
+    params = stacked
+    if rounds == 2:
+        first = FedEngine(LLMDSFLAlgorithm(CFG, hp))
+        params = first.run(first.algo.init_from(stacked), task,
+                           rounds=1).clients.params
+    rng = jax.random.PRNGKey(hp.seed)
+    for _ in range(rounds):
+        rng, _, ri = jax.random.split(rng, 3)
+    o_idx = jax.random.choice(ri, B, (B,), replace=False)
+    open_b = jax.tree.map(lambda a: jnp.take(a, o_idx, axis=0), task.open_x)
+    (probs,) = dsfl_exchange(CFG, params, open_b, hp)
+    want = np.asarray(sa(probs)).reshape(-1, probs.shape[-1])[list(PROBE)]
+    assert got.shape == (len(PROBE), CFG.vocab) and got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
+
+
+def test_llm_dsfl_probe_refuses_the_edge_tree(task, stacked):
+    """Through the two-level edge tree no whole client mean exists."""
+    hp = LLMDsflHP(lr=5e-3, rounds=1, seed=0, open_batch=B, agg_edges=2)
+    algo = LLMDSFLAlgorithm(CFG, hp, probe_rows=PROBE)
+    with pytest.raises(ValueError, match="agg_edges"):
+        FedEngine(algo).run(algo.init_from(stacked), task, rounds=1)
 
 
 # ------------------------------------------------------- wire/comm parity ----
