@@ -1,8 +1,13 @@
 """DS-FL at pod scale: each federated client is one pod of the production
 mesh.  Client-stacked parameters (n_clients, ...) are sharded P("pod", ...),
-so the ONLY cross-pod collective in a DS-FL round is the open-batch logit
-mean inside ``aggregate`` — the paper's communication claim, visible directly
-as all-reduce bytes in the compiled HLO (vs. FedAvg's parameter all-reduce).
+so the ONLY cross-pod collective in a DS-FL round is the open-batch
+prediction exchange inside ``aggregate`` — the paper's communication claim,
+visible directly as collective bytes in the compiled HLO (vs. FedAvg's
+parameter all-reduce).  On a pod mesh the dense exchange is token-sharded
+(`_token_sharded_aggregate`): a bf16 all-to-all hands each pod every
+client's uploads for its share of the open tokens, the pod takes the
+float32 mean and ERA there, and a bf16 all-gather returns the teacher —
+2 bytes an entry on the wire, as `wire.FP16Codec` counts them.
 
 Step functions here are mesh-agnostic pure JAX; launch/ assigns shardings.
 
@@ -16,13 +21,17 @@ implementations the algorithm wrappers are pinned against bit-for-bit
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..models.api import model_logits
 from ..models.base import ModelConfig
+from ..models.shardctx import current_mesh
+from ..obs import trace as obs
 from .aggregation import sa, topk_compress, weighted_sa
 from .hierarchy import hierarchical_weighted_era, hierarchical_weighted_sa
 from .algorithms import (AGGREGATE, DISTILL, PREDICT, UPDATE, active_indices,
@@ -234,7 +243,7 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
             dense = jnp.einsum("cbsk,cbskv->cbsv", tv.astype(jnp.float32),
                                onehot, precision=HIGHEST)
             dense = constrain(dense, None, "batch", None, "model")
-            teacher, mean = _aggregate(dense, hp, weights)
+            teacher, probe = _aggregate(dense, hp, weights, probe_rows)
             teacher = constrain(teacher, "batch", None, "model")
         # the exchange leg is compressed; the pod-local distillation uses the
         # dense (vocab-sharded) teacher — no top_k over a sharded axis
@@ -243,7 +252,7 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
     else:
         (probs,) = inflight
         with jax.named_scope(AGGREGATE):
-            teacher, mean = _aggregate(probs, hp, weights)
+            teacher, probe = _aggregate(probs, hp, weights, probe_rows)
 
     new_params, losses = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
@@ -256,7 +265,7 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
         loss = masked_mean(losses, m)
     else:
         loss = jnp.mean(losses)
-    return _with_probe(new_params, loss, mean, probe_rows)
+    return _with_probe(new_params, loss, probe)
 
 
 def dsfl_round_step(cfg: ModelConfig, stacked_params, private_batches,
@@ -269,9 +278,12 @@ def dsfl_round_step(cfg: ModelConfig, stacked_params, private_batches,
     private_batches: each leaf (n_clients, B, ...).  open_batch: (B, ...) —
     identical on every pod (the shared open set).
 
-    The mean over axis 0 inside sa/era is the ONLY cross-pod collective.
-    With hp.topk, clients compress their logits BEFORE the exchange (the
-    paper's upload leg): the cross-pod traffic becomes an all-gather of
+    The exchange inside sa/era is the ONLY cross-pod collective: on a pod
+    mesh a bf16 all-to-all of the uploads and a bf16 all-gather of the
+    teacher, the float32 mean taken between them on each pod's share of
+    the tokens (`_token_sharded_aggregate`).  With hp.topk, clients
+    compress their logits BEFORE the exchange (the paper's upload leg):
+    the cross-pod traffic becomes an all-gather of
     (value, index) pairs — k*(4+4) bytes/token instead of V*2 — and the
     dense densify+ERA runs pod-locally on the gathered pairs.
 
@@ -304,17 +316,10 @@ def dsfl_round_step(cfg: ModelConfig, stacked_params, private_batches,
                              probe_rows=probe_rows)
 
 
-def _with_probe(new_params, loss, mean, probe_rows):
-    """The round's results, with the aggregate's ``probe_rows`` rows when
+def _with_probe(new_params, loss, probe):
+    """The round's results, with the aggregate at the probe rows when
     asked for."""
-    if probe_rows is None:
-        return new_params, loss
-    if mean is None:
-        raise ValueError("probe_rows needs the one-level aggregation "
-                         "(agg_edges=1): the edge tree never forms the "
-                         "whole client mean")
-    rows = jnp.asarray(probe_rows, jnp.int32)
-    return new_params, loss, mean.reshape(-1, mean.shape[-1])[rows]
+    return (new_params, loss) if probe is None else (new_params, loss, probe)
 
 
 def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
@@ -338,8 +343,8 @@ def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
 
     (probs_m,) = inflight                                   # (m, B, S, V)
     with jax.named_scope(AGGREGATE):
-        teacher, mean = _aggregate(scatter_zeros(probs_m, K, idx), hp,
-                                   weights)
+        teacher, probe = _aggregate(scatter_zeros(probs_m, K, idx), hp,
+                                    weights, probe_rows)
 
     new_m, losses_m = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
@@ -351,13 +356,15 @@ def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
     losses = scatter_zeros(losses_m, K, idx)
     return _with_probe(new_params,
                        masked_mean(losses, act.astype(jnp.float32) > 0),
-                       mean, probe_rows)
+                       probe)
 
 
 def client_mean(probs, weights):
     """The exchanged aggregate: the clients' float32 mean prediction (SA,
-    Eq. 16), weighted when the sim supplies ``weights``.  The mean over
-    the pod-sharded client axis is the round's cross-pod all-reduce."""
+    Eq. 16), weighted when the sim supplies ``weights``.  Over a
+    pod-sharded client axis GSPMD makes this mean a float32 all-reduce;
+    the unweighted one-level round on a pod mesh takes it on each pod's
+    share of the tokens instead (`_token_sharded_aggregate`)."""
     return sa(probs) if weights is None else weighted_sa(probs, weights)
 
 
@@ -373,11 +380,29 @@ def aggregate_teacher(probs, hp: LLMDsflHP, weights):
     return _aggregate(probs, hp, weights)[0]
 
 
-def _aggregate(probs, hp: LLMDsflHP, weights):
-    """`aggregate_teacher`'s teacher (bfloat16) and the `client_mean` it
-    was formed from (None through the edge tree).  ERA is
-    softmax(mean / T), as `aggregation.era`/`weighted_era` compute it."""
+def _aggregate(probs, hp: LLMDsflHP, weights, probe_rows=None):
+    """`aggregate_teacher`'s teacher (bfloat16) and, at ``probe_rows``
+    (flat token indices), the `client_mean` it was formed from, (n, V)
+    float32 (None without ``probe_rows``).
+
+    The unweighted one-level dense round on a mesh whose "pod" axis
+    splits both the clients and the tokens runs token-sharded
+    (`_token_sharded_aggregate`), bitwise the same teacher and mean;
+    every other round takes the mean replicated.  Each trace counts the
+    exchange it took on the installed registry: ``dsfl.exchange.
+    token_sharded`` or ``dsfl.exchange.replicated``."""
+    mesh = _token_mesh(probs, hp, weights)
+    reg = obs.current_registry()
+    if reg is not None:
+        reg.counter("dsfl.exchange." + ("replicated" if mesh is None
+                                        else "token_sharded")).inc()
+    if mesh is not None:
+        return _token_sharded_aggregate(probs, hp, mesh, probe_rows)
     if hp.agg_edges > 1:
+        if probe_rows is not None:
+            raise ValueError("probe_rows needs the one-level aggregation "
+                             "(agg_edges=1): the edge tree never forms the "
+                             "whole client mean")
         w = (jnp.ones((probs.shape[0],), jnp.float32)
              if weights is None else weights)
         agg = (hierarchical_weighted_era(probs, w, hp.temperature,
@@ -386,9 +411,73 @@ def _aggregate(probs, hp: LLMDsflHP, weights):
                else hierarchical_weighted_sa(probs, w, hp.agg_edges))
         return agg.astype(jnp.bfloat16), None
     mean = client_mean(probs, weights)
+    probe = (None if probe_rows is None else
+             mean.reshape(-1, mean.shape[-1])[jnp.asarray(probe_rows,
+                                                          jnp.int32)])
+    return _sharpen(mean, hp), probe
+
+
+def _sharpen(mean, hp: LLMDsflHP):
+    """The bfloat16 teacher from the client mean: ERA is softmax(mean /
+    T), as `aggregation.era`/`weighted_era` compute it; SA is the mean."""
     agg = (jax.nn.softmax(mean / hp.temperature, axis=-1)
            if hp.aggregation == "era" else mean)
-    return agg.astype(jnp.bfloat16), mean
+    return agg.astype(jnp.bfloat16)
+
+
+def _token_mesh(probs, hp: LLMDsflHP, weights):
+    """The launcher mesh whose "pod" axis (size P > 1) divides both the
+    client axis and the open tokens, for the unweighted one-level dense
+    round; None for every other round."""
+    mesh = current_mesh()
+    if (mesh is None or "pod" not in mesh.axis_names or hp.agg_edges > 1
+            or hp.topk is not None or weights is not None):
+        return None
+    n_pod = mesh.shape["pod"]
+    n_tok = math.prod(probs.shape[1:-1])
+    ok = n_pod > 1 and probs.shape[0] % n_pod == 0 and n_tok % n_pod == 0
+    return mesh if ok else None
+
+
+def _token_sharded_aggregate(probs, hp: LLMDsflHP, mesh, probe_rows):
+    """`_aggregate` over the "pod" axis, each pod on its 1/P of the tokens.
+
+    An all-to-all hands each pod every client's bfloat16 uploads for its
+    tokens, (K, n/P, V); the pod takes the float32 `client_mean` and the
+    teacher there; a tiled all-gather returns the whole bfloat16 teacher
+    to every pod.  Both collectives move uint16 bit patterns, 2 bytes an
+    entry (a backend may widen a bfloat16 collective to float32).  The
+    probe rows are taken where they live and summed over the pods with
+    zeros elsewhere, so no collective carries the whole float32 mean."""
+    from jax.sharding import PartitionSpec as P
+    n_pod = mesh.shape["pod"]
+    shape = probs.shape[1:]
+    n_tok, V = math.prod(shape[:-1]), shape[-1]
+    n_loc = n_tok // n_pod
+    rows = None if probe_rows is None else np.asarray(probe_rows, np.int64)
+
+    def bits(x, dtype):
+        return jax.lax.bitcast_convert_type(x, dtype)
+
+    def body(x):                                    # (K/P, B, S, V) bf16
+        # (P, K/P, n/P, V): the pod-major split keeps V the minor axis
+        # (splitting the token axis in place compiles slowly on the TPU)
+        x = jnp.moveaxis(x.reshape(x.shape[0], n_pod, n_loc, V), 1, 0)
+        x = jax.lax.all_to_all(bits(x, jnp.uint16), "pod", 0, 0)
+        mean = client_mean(bits(x, jnp.bfloat16).reshape(-1, n_loc, V),
+                           None)                              # (n/P, V)
+        teacher = bits(jax.lax.all_gather(
+            bits(_sharpen(mean, hp), jnp.uint16), "pod", axis=0, tiled=True),
+            jnp.bfloat16).reshape(shape)
+        if rows is None:
+            return teacher, None
+        mine = jnp.asarray(rows // n_loc) == jax.lax.axis_index("pod")
+        probe = jnp.where(mine[:, None], mean[jnp.asarray(rows % n_loc)], 0.0)
+        return teacher, jax.lax.psum(probe, "pod")
+
+    return jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                         out_specs=(P(), None if rows is None else P()),
+                         axis_names={"pod"}, check_vma=False)(probs)
 
 
 def fedavg_round_step(cfg: ModelConfig, stacked_params, private_batches,

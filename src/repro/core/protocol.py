@@ -1,7 +1,7 @@
 """DS-FL engine (paper Algorithm 1) at "paper scale": K clients simulated as
 a vmapped leading axis of stacked parameter pytrees; the server's aggregation
 is a mean over that axis (on a TPU mesh this axis is sharded over pods and
-the mean lowers to the logit all-reduce — see core/llm_dsfl.py).
+the mean becomes the cross-pod logit exchange — see core/llm_dsfl.py).
 
 Round structure (Fig. 1 (c)):
   1. Update       - local SGD on private data (client.local_update over

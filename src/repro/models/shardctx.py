@@ -60,3 +60,9 @@ def constrain(x: jax.Array, *dims):
             spec.append(None)
     return jax.lax.with_sharding_constraint(
         x, jax.sharding.NamedSharding(ctx["mesh"], P(*spec)))
+
+
+def current_mesh():
+    """The launcher context's mesh, or None outside `axis_ctx`."""
+    ctx = _state()
+    return None if ctx is None else ctx["mesh"]
