@@ -19,7 +19,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .losses import HIGHEST, pinned_sum
+from .losses import HIGHEST, entropy, pinned_sum
 
 F32 = jnp.float32
 
@@ -123,6 +123,14 @@ def participation_weights(mask: jax.Array, staleness: jax.Array | None = None,
     if staleness is not None:
         w = w * jnp.power(jnp.asarray(decay, F32), staleness.astype(F32))
     return jnp.where(jnp.sum(w) > 0, w, mask.astype(F32))
+
+
+def entropy_reliability(local_probs: jax.Array) -> jax.Array:
+    """(K, N, C) uploads -> (K,) reliability weights for weighted ERA
+    (paper §5 "future work"): the inverse mean entropy of each client's
+    soft labels, re-estimated every round, so diffuse (unreliable) uploads
+    count less."""
+    return 1.0 / (jnp.mean(entropy(local_probs), axis=-1) + 1e-3)
 
 
 def aggregate(local_probs: jax.Array, method: str = "era",

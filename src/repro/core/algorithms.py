@@ -41,8 +41,8 @@ import jax.numpy as jnp
 
 from ..optim import optimizers as opt_lib
 from . import fd as fd_lib
-from .aggregation import (aggregate, participation_weights, weighted_era,
-                          weighted_sa)
+from .aggregation import (aggregate, entropy_reliability,
+                          participation_weights, weighted_sa)
 from .client import (LocalSpec, local_distill, local_update, over_clients,
                      predict_probs)
 from .fedavg import weighted_average
@@ -260,6 +260,77 @@ def scatter_zeros(values_m, K: int, idx):
                      ).at[idx].set(values_m)
 
 
+@dataclass(frozen=True)
+class LanePlan:
+    """Which client lanes a round computes, decided once per round.
+
+    * full (``mask`` None): every lane, every result kept;
+    * masked: every lane, absent clients' results discarded by
+      `select_clients`;
+    * sparse (``idx`` set): only the <= m lanes `active_indices` picks,
+      gathered out of the (K, ...) stack and scattered back — bitwise the
+      masked round (padding lanes carry exactly zero aggregation weight).
+
+    A round body is written once against these operations.  On the full
+    plane each returns its argument object unchanged, so the full round's
+    program is the plain dense one, op for op."""
+    K: int
+    mask: Any = None         # (K,) participation; None on the full plane
+    idx: Any = None          # (m,) computed lanes; None unless sparse
+    lane_mask: Any = None    # participation of the computed lanes
+
+    @classmethod
+    def of(cls, K: int, mask=None, budget: Optional[int] = None,
+           dense: bool = False) -> "LanePlan":
+        """The plane for participation ``mask`` (or None) under the static
+        budget m: sparse when ``budget < K``, unless ``dense`` — the
+        algorithm's veto for a step that needs every lane's upload."""
+        if mask is None:
+            return cls(K)
+        if dense or budget is None or budget >= K:
+            return cls(K, mask, lane_mask=mask)
+        idx = active_indices(mask, budget)
+        return cls(K, mask, idx, jnp.take(mask, idx, axis=0))
+
+    @classmethod
+    def for_round(cls, ctx: BatchCtx, dense: bool = False) -> "LanePlan":
+        return cls.of(ctx.x.shape[0], ctx.mask if present(ctx.mask) else None,
+                      ctx.active_budget, dense)
+
+    def take(self, tree):
+        """A client-axis tree on the computed lanes."""
+        return tree if self.idx is None else gather_clients(tree, self.idx)
+
+    def keys(self, rng, ctx: BatchCtx):
+        """The computed lanes' rows of `client_keys`: every client consumes
+        bitwise its dense-round key."""
+        return self.take(client_keys(rng, ctx, self.K))
+
+    def keep(self, new, old):
+        """Computed-lane results, absent clients keeping ``old``."""
+        if self.mask is None:
+            return new
+        return select_clients(self.lane_mask, new, old)
+
+    def put(self, lanes, full):
+        """Computed lanes written back into the (K, ...) stack ``full``."""
+        return lanes if self.idx is None else scatter_clients(lanes, full,
+                                                              self.idx)
+
+    def zeros(self, tree):
+        """Computed-lane results on all K lanes, exact zeros elsewhere."""
+        if self.idx is None:
+            return tree
+        return jax.tree.map(lambda a: scatter_zeros(a, self.K, self.idx),
+                            tree)
+
+    def mean(self, values):
+        """The mean of per-lane values (losses) over the participants."""
+        if self.mask is None:
+            return jnp.mean(values)
+        return masked_mean(self.zeros(values), self.mask)
+
+
 # ---------------------------------------------------------------- DS-FL ------
 @dataclass(frozen=True)
 class DSFLAlgorithm:
@@ -339,42 +410,39 @@ class DSFLAlgorithm:
                            opt_update=jax.vmap(spec_u.opt.init)(wk),
                            opt_distill=jax.vmap(spec_d.opt.init)(wk))
 
-    def _masked_teacher(self, probs, ctx: BatchCtx):
-        """"3-5. Upload / Aggregation / Broadcast" of a masked round, over
-        the full (K, n, C) upload stack.  Shared verbatim by the dense
-        masked path and the participation-sparse path: the sparse plane
-        scatters its computed prediction lanes into exact zeros, and every
-        reduction here multiplies non-participant lanes by an exact-zero
-        weight (``0.0 * x == 0.0`` for the finite probabilities crossing
-        the wire) — which is what makes the two paths bitwise identical."""
+    def _teacher(self, probs, ctx: BatchCtx):
+        """"3-5. Upload / Aggregation / Broadcast" over the (K, n, C) upload
+        stack: (aggregation weights or None, global logit, SA entropy).  On
+        a masked round every reduction multiplies absent lanes by an
+        exact-zero weight (``0.0 * x == 0.0`` for the finite probabilities
+        crossing the wire), so the sparse plane's exact-zero lanes give
+        bitwise the dense masked teacher."""
         hp = self.hp
+        masked = present(ctx.mask)
         agg_w = self.agg_weights
         if agg_w is None and hp.aggregation == "weighted_era":
-            # adaptive reliability (paper §5 "future work"): inverse mean
-            # entropy of each client's uploaded soft labels — absent lanes
-            # get a finite garbage value that the mask zeroes exactly
-            ent_k = jnp.mean(entropy(probs), axis=-1)           # (K,)
-            agg_w = 1.0 / (ent_k + 1e-3)
-        pw = participation_weights(
+            # absent lanes get a finite value that the mask zeroes exactly
+            agg_w = entropy_reliability(probs)
+        pw = agg_w if not masked else participation_weights(
             ctx.mask, ctx.stale if present(ctx.stale) else None,
             hp.staleness_decay, base=agg_w)
         if self.agg_edges > 1:
+            w = jnp.ones((probs.shape[0],), jnp.float32) if pw is None else pw
             global_logit = (
-                hierarchical_weighted_sa(probs, pw, self.agg_edges,
+                hierarchical_weighted_sa(probs, w, self.agg_edges,
                                          use_kernel=self.use_kernel)
                 if hp.aggregation == "sa"
-                else hierarchical_weighted_era(probs, pw, hp.temperature,
+                else hierarchical_weighted_era(probs, w, hp.temperature,
                                                self.agg_edges,
                                                use_kernel=self.use_kernel))
         else:
-            global_logit = (
-                weighted_sa(probs, pw, use_kernel=self.use_kernel)
-                if hp.aggregation == "sa"
-                else weighted_era(probs, pw, hp.temperature,
-                                  use_kernel=self.use_kernel))
+            global_logit = aggregate(probs, hp.aggregation, hp.temperature,
+                                     weights=pw, use_kernel=self.use_kernel)
         # the unsharpened SA diagnostic over the uploads that actually
         # happened: mask-weighted, since absent clients upload nothing
-        sa_entropy = jnp.mean(entropy(weighted_sa(probs, ctx.mask)))
+        sa_entropy = jnp.mean(entropy(
+            weighted_sa(probs, ctx.mask) if masked
+            else jnp.mean(probs, axis=0)))
         return pw, global_logit, sa_entropy
 
     def round(self, state: RoundState, ctx: BatchCtx, rng):
@@ -385,45 +453,36 @@ class DSFLAlgorithm:
         return self.round_finish(state, ctx,
                                  self.round_start(state, ctx, rng), rng)
 
-    def _is_sparse(self, ctx: BatchCtx) -> bool:
-        """Static predicate routing a round through the participation-sparse
-        gather plane (`corrupt` sees the full upload stack, so it keeps the
-        dense path — attack evaluation is not a perf path).  Shared by both
-        halves so a split round can never disagree about its plane."""
-        K = ctx.x.shape[0]
-        return (present(ctx.mask) and ctx.active_budget is not None
-                and ctx.active_budget < K and self.corrupt is None)
+    def _lanes(self, ctx: BatchCtx) -> LanePlan:
+        """The round's lane plan, the same in both halves.  `corrupt` sees
+        the whole upload stack, so it keeps every lane (attack evaluation
+        is not a perf path)."""
+        return LanePlan.for_round(ctx, dense=self.corrupt is not None)
 
     def round_start(self, state: RoundState, ctx: BatchCtx, rng):
         """"1. Update" + "2. Prediction": everything up to (and including)
         the round's upload — the leg that depends only on the round's input
-        state.  Returns the in-flight `(wk, sk, ouk, up_loss, probs)`
-        buffers `round_finish` consumes (m-lane on the sparse plane).  Both
-        halves draw the full ``split(rng, 4)`` so every sub-key lands on
-        bitwise the fused round's consumer."""
+        state.  Returns the computed lanes' in-flight `(wk, sk, ouk,
+        up_loss, probs)` buffers `round_finish` consumes (O(m) on the
+        sparse plane: the finish leg re-derives the lanes).  Both halves
+        draw the full ``split(rng, 4)`` so every sub-key lands on bitwise
+        the fused round's consumer."""
         spec_u, _ = self._specs()
-        wk, sk = state.clients.params, state.clients.model_state
-        ouk = state.clients.opt_update
-        K = ctx.x.shape[0]
-        masked = present(ctx.mask)
-        if self._is_sparse(ctx):
-            return self._sparse_start(state, ctx, rng, ctx.active_budget)
+        c = state.clients
         r1, _r2, r3, _r4 = jax.random.split(rng, 4)
         with jax.named_scope(PREDICT):
             xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
 
-        # 1. Update (always computed for the full stack — a fused where keeps
-        # absent clients' state; no per-client Python loop, shards cleanly)
+        # 1. Update on the computed lanes (absent clients keep their state)
         with jax.named_scope(UPDATE):
+            lanes = self._lanes(ctx)
+            x, y = lanes.take((ctx.x, ctx.y))
+            wk, sk, ouk = lanes.take((c.params, c.model_state, c.opt_update))
             wk_n, sk_n, ouk_n, up_loss = over_clients(
                 lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk,
                                                          yk, rk),
-                wk, sk, ouk, ctx.x, ctx.y, client_keys(r1, ctx, K))
-            if masked:
-                wk, sk, ouk = select_clients(ctx.mask, (wk_n, sk_n, ouk_n),
-                                             (wk, sk, ouk))
-            else:
-                wk, sk, ouk = wk_n, sk_n, ouk_n
+                wk, sk, ouk, x, y, lanes.keys(r1, ctx))
+            wk, sk, ouk = lanes.keep((wk_n, sk_n, ouk_n), (wk, sk, ouk))
 
         # 2. Prediction (local logits on o_r)
         with jax.named_scope(PREDICT):
@@ -437,51 +496,23 @@ class DSFLAlgorithm:
         """"3-6'. Upload / Aggregation / Broadcast / Distillation": consume
         the in-flight upload buffers.  ``state`` supplies only what the
         start leg did not touch (distill optimizers + the server model)."""
-        hp = self.hp
         _spec_u, spec_d = self._specs()
-        odk = state.clients.opt_distill
+        c = state.clients
         wg, sg = state.server.params, state.server.model_state
         odg = state.server.opt_distill
-        K = ctx.x.shape[0]
-        masked = present(ctx.mask)
-        if self._is_sparse(ctx):
-            return self._sparse_finish(state, ctx, inflight, rng,
-                                       ctx.active_budget)
         _r1, r2, _r3, r4 = jax.random.split(rng, 4)
         with jax.named_scope(PREDICT):
             xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
+        with jax.named_scope(AGGREGATE):
+            lanes = self._lanes(ctx)
+        with jax.named_scope(DISTILL):
+            odk = lanes.take(c.opt_distill)
         wk, sk, ouk, up_loss, probs = inflight
 
-        # 3-5. Upload / Aggregation / Broadcast
+        # 3-5. Upload / Aggregation / Broadcast over all K upload lanes
         with jax.named_scope(AGGREGATE):
-            if masked:
-                pw, global_logit, sa_entropy = self._masked_teacher(probs,
-                                                                    ctx)
-            else:
-                agg_w = self.agg_weights
-                if agg_w is None and hp.aggregation == "weighted_era":
-                    # adaptive reliability (paper §5 "future work"): inverse
-                    # mean entropy of each client's uploaded soft labels,
-                    # re-estimated every round — diffuse (unreliable)
-                    # uploads get down-weighted
-                    ent_k = jnp.mean(entropy(probs), axis=-1)   # (K,)
-                    agg_w = 1.0 / (ent_k + 1e-3)
-                pw = agg_w
-                if self.agg_edges > 1:
-                    w = (jnp.ones((K,), jnp.float32) if agg_w is None
-                         else agg_w)
-                    global_logit = (
-                        hierarchical_weighted_sa(probs, w, self.agg_edges,
-                                                 use_kernel=self.use_kernel)
-                        if hp.aggregation == "sa"
-                        else hierarchical_weighted_era(
-                            probs, w, hp.temperature, self.agg_edges,
-                            use_kernel=self.use_kernel))
-                else:
-                    global_logit = aggregate(probs, hp.aggregation,
-                                             hp.temperature, weights=agg_w,
-                                             use_kernel=self.use_kernel)
-                sa_entropy = jnp.mean(entropy(jnp.mean(probs, axis=0)))
+            pw, global_logit, sa_entropy = self._teacher(lanes.zeros(probs),
+                                                         ctx)
             g_entropy = jnp.mean(entropy(global_logit))
 
         # 6. Distillation (clients, Eq. 10; absent clients keep their state)
@@ -489,22 +520,18 @@ class DSFLAlgorithm:
             wk_n, sk_n, odk_n, d_loss = over_clients(
                 lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
                                                   global_logit, rk),
-                wk, sk, odk, client_keys(r2, ctx, K))
-            if masked:
-                wk, sk, odk = select_clients(ctx.mask, (wk_n, sk_n, odk_n),
-                                             (wk, sk, odk))
-            else:
-                wk, sk, odk = wk_n, sk_n, odk_n
+                wk, sk, odk, lanes.keys(r2, ctx))
+            wk, sk, odk = lanes.keep((wk_n, sk_n, odk_n), (wk, sk, odk))
 
         # 6'. server global model (Eq. 11), with its own key r4
         with jax.named_scope(SERVER_DISTILL):
             wg, sg, odg, gd_loss = local_distill(spec_d, wg, sg, odg, xo,
                                                  global_logit, r4)
 
-        metrics = {"update_loss": (masked_mean(up_loss, ctx.mask) if masked
-                                   else jnp.mean(up_loss)),
-                   "distill_loss": (masked_mean(d_loss, ctx.mask) if masked
-                                    else jnp.mean(d_loss)),
+        with jax.named_scope(DISTILL):
+            clients = lanes.put(ClientState(wk, sk, ouk, odk), c)
+        metrics = {"update_loss": lanes.mean(up_loss),
+                   "distill_loss": lanes.mean(d_loss),
                    "server_distill_loss": gd_loss,
                    "global_entropy": g_entropy,
                    "sa_entropy": sa_entropy}
@@ -514,111 +541,8 @@ class DSFLAlgorithm:
             # pinned total so the diagnostic agrees bitwise across the
             # dense-masked and sparse programs like every other reduction
             metrics["agg_weights"] = pw / jnp.maximum(pinned_sum(pw), 1e-9)
-        if masked:
+        if lanes.mask is not None:
             metrics["participants"] = jnp.sum(ctx.mask.astype(jnp.float32))
-        new = RoundState(
-            clients=ClientState(wk, sk, ouk, odk),
-            server=ServerState(wg, sg, odg))
-        return new, metrics
-
-    def _sparse_start(self, state: RoundState, ctx: BatchCtx, rng, m: int):
-        """Participation-sparse start leg: gather the <= m active lanes of
-        the client stack and run "1. Update" / "2. Prediction" over only
-        the (m, ...) slice — ~K/m less client compute and activation
-        memory, **bitwise identical** to the dense masked round (pinned by
-        tests/test_engine_scan.py): per-client math sees the same inputs
-        and the same per-client keys, and padding lanes carry exactly zero
-        aggregation weight.  Returns the m-lane in-flight buffers; ``idx``
-        is re-derived by the finish leg (a pure, cheap argsort), keeping
-        the exchange buffers O(m)."""
-        spec_u, _ = self._specs()
-        wk, sk = state.clients.params, state.clients.model_state
-        ouk = state.clients.opt_update
-        K = ctx.x.shape[0]
-        # identical key discipline to the dense round (r3 would feed
-        # `corrupt`, which forces the dense path; split to keep key parity)
-        r1, _r2, _r3, _r4 = jax.random.split(rng, 4)
-        with jax.named_scope(PREDICT):
-            xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
-
-        # 1. Update — only the gathered lanes; per-client keys gathered out
-        # of the same (K,) split the dense round draws, so every active
-        # client consumes bitwise its dense-path key
-        with jax.named_scope(UPDATE):
-            idx = active_indices(ctx.mask, m)
-            mask_m = jnp.take(ctx.mask, idx, axis=0)
-            x_m, y_m = gather_clients((ctx.x, ctx.y), idx)
-            wk_m, sk_m, ouk_m = gather_clients((wk, sk, ouk), idx)
-            wk_n, sk_n, ouk_n, up_loss = over_clients(
-                lambda w, s, o, xk, yk, rk: local_update(spec_u, w, s, o, xk,
-                                                         yk, rk),
-                wk_m, sk_m, ouk_m, x_m, y_m,
-                jnp.take(client_keys(r1, ctx, K), idx, axis=0))
-            wk_m, sk_m, ouk_m = select_clients(mask_m, (wk_n, sk_n, ouk_n),
-                                               (wk_m, sk_m, ouk_m))
-
-        # 2. Prediction on the active lanes (the finish leg scatters into
-        # exact zeros so the masked aggregation sees its (K, n, C) stack)
-        with jax.named_scope(PREDICT):
-            probs_m = jax.vmap(lambda w, s: predict_probs(self.apply_fn, w,
-                                                          s, xo))(wk_m, sk_m)
-        return (wk_m, sk_m, ouk_m, up_loss, probs_m)
-
-    def _sparse_finish(self, state: RoundState, ctx: BatchCtx, inflight,
-                       rng, m: int):
-        """Participation-sparse finish leg: scatter the in-flight m-lane
-        uploads into the shared masked aggregation, distill the gathered
-        lanes, and scatter results back into the dense stacks."""
-        _spec_u, spec_d = self._specs()
-        wk, sk = state.clients.params, state.clients.model_state
-        ouk, odk = state.clients.opt_update, state.clients.opt_distill
-        wg, sg = state.server.params, state.server.model_state
-        odg = state.server.opt_distill
-        K = ctx.x.shape[0]
-        _r1, r2, _r3, r4 = jax.random.split(rng, 4)
-        with jax.named_scope(PREDICT):
-            xo = jnp.take(ctx.open_x, ctx.o_idx, axis=0)
-
-        with jax.named_scope(AGGREGATE):
-            idx = active_indices(ctx.mask, m)
-        with jax.named_scope(DISTILL):
-            mask_m = jnp.take(ctx.mask, idx, axis=0)
-            odk_m = gather_clients(odk, idx)
-        wk_m, sk_m, ouk_m, up_loss, probs_m = inflight
-
-        # 3-5. verbatim the dense masked aggregation on the scattered stack
-        with jax.named_scope(AGGREGATE):
-            probs = scatter_zeros(probs_m, K, idx)
-            pw, global_logit, sa_entropy = self._masked_teacher(probs, ctx)
-            g_entropy = jnp.mean(entropy(global_logit))
-
-        # 6. Distillation (clients) on the gathered lanes
-        with jax.named_scope(DISTILL):
-            wk_n, sk_n, odk_n, d_loss = over_clients(
-                lambda w, s, o, rk: local_distill(spec_d, w, s, o, xo,
-                                                  global_logit, rk),
-                wk_m, sk_m, odk_m,
-                jnp.take(client_keys(r2, ctx, K), idx, axis=0))
-            wk_m, sk_m, odk_m = select_clients(mask_m, (wk_n, sk_n, odk_n),
-                                               (wk_m, sk_m, odk_m))
-
-        # 6'. server global model (Eq. 11), with its own key r4
-        with jax.named_scope(SERVER_DISTILL):
-            wg, sg, odg, gd_loss = local_distill(spec_d, wg, sg, odg, xo,
-                                                 global_logit, r4)
-
-        with jax.named_scope(DISTILL):
-            clients = ClientState(*scatter_clients(
-                (wk_m, sk_m, ouk_m, odk_m), (wk, sk, ouk, odk), idx))
-        metrics = {"update_loss": masked_mean(scatter_zeros(up_loss, K, idx),
-                                              ctx.mask),
-                   "distill_loss": masked_mean(scatter_zeros(d_loss, K, idx),
-                                               ctx.mask),
-                   "server_distill_loss": gd_loss,
-                   "global_entropy": g_entropy,
-                   "sa_entropy": sa_entropy,
-                   "agg_weights": pw / jnp.maximum(pinned_sum(pw), 1e-9),
-                   "participants": jnp.sum(ctx.mask.astype(jnp.float32))}
         return RoundState(clients=clients,
                           server=ServerState(wg, sg, odg)), metrics
 
@@ -687,79 +611,32 @@ class FDAlgorithm:
     def round(self, state: RoundState, ctx: BatchCtx, rng):
         hp = self.hp
         spec = self._spec()
-        wk, sk = state.clients.params, state.clients.model_state
-        ok = state.clients.opt_update
-        K = ctx.x.shape[0]
-        masked = present(ctx.mask)
-        if (masked and ctx.active_budget is not None
-                and ctx.active_budget < K):
-            return self._sparse_round(state, ctx, rng, ctx.active_budget)
+        c = state.clients
+        lanes = LanePlan.for_round(ctx)
+        x, y = lanes.take((ctx.x, ctx.y))
+        wk, sk, ok = lanes.take((c.params, c.model_state, c.opt_update))
         tk, owns = jax.vmap(
             lambda w, s, xk, yk: fd_lib.per_label_logits(
-                self.apply_fn, w, s, xk, yk, hp.n_classes))(wk, sk, ctx.x, ctx.y)
-        if masked:
+                self.apply_fn, w, s, xk, yk, hp.n_classes))(wk, sk, x, y)
+        if lanes.mask is not None:
             # absent clients' per-class tables leave the Eq. 5 mean entirely
-            owns = jnp.logical_and(owns, ctx.mask.astype(bool)[:, None])
-        tg, n_own = fd_lib.aggregate_fd(tk, owns)
-        rngs = client_keys(rng, ctx, K)
+            owns = jnp.logical_and(owns, lanes.lane_mask.astype(bool)[:, None])
+        # lanes not computed join as (zeros, False): the same Eq. 5 terms as
+        # the masked round's (finite table, False-by-mask) lanes
+        tg, n_own = fd_lib.aggregate_fd(*lanes.zeros((tk, owns)))
 
         def per_client(w, s, o, xk, yk, tkk, rk):
             tgt = fd_lib.distill_targets(tg, tkk, n_own, yk)
             return local_update(spec, w, s, o, xk, yk, rk,
                                 distill_extra=tgt, gamma=hp.gamma)
 
-        wk_n, sk_n, ok_n, losses = jax.vmap(per_client)(wk, sk, ok, ctx.x,
-                                                        ctx.y, tk, rngs)
-        if masked:
-            wk, sk, ok = select_clients(ctx.mask, (wk_n, sk_n, ok_n),
-                                        (wk, sk, ok))
-        else:
-            wk, sk, ok = wk_n, sk_n, ok_n
-        metrics = {"update_loss": (masked_mean(losses, ctx.mask) if masked
-                                   else jnp.mean(losses)),
+        wk_n, sk_n, ok_n, losses = jax.vmap(per_client)(
+            wk, sk, ok, x, y, tk, lanes.keys(rng, ctx))
+        clients = lanes.put(
+            ClientState(*lanes.keep((wk_n, sk_n, ok_n), (wk, sk, ok))), c)
+        metrics = {"update_loss": lanes.mean(losses),
                    "global_logit": tg}        # (C, C), for Fig. 2 analysis
-        return RoundState(clients=ClientState(wk, sk, ok)), metrics
-
-    def _sparse_round(self, state: RoundState, ctx: BatchCtx, rng, m: int):
-        """Participation-sparse FD round: per-class tables and the Eq. 7
-        update run only on the <= m gathered active lanes; the Eq. 5 mean
-        sees scattered zero tables whose ``owns`` rows are False — exactly
-        the lanes the dense masked round multiplies by zero."""
-        hp = self.hp
-        spec = self._spec()
-        wk, sk = state.clients.params, state.clients.model_state
-        ok = state.clients.opt_update
-        K = ctx.x.shape[0]
-        idx = active_indices(ctx.mask, m)
-        mask_m = jnp.take(ctx.mask, idx, axis=0)
-        x_m, y_m = gather_clients((ctx.x, ctx.y), idx)
-        wk_m, sk_m, ok_m = gather_clients((wk, sk, ok), idx)
-
-        tk_m, owns_m = jax.vmap(
-            lambda w, s, xk, yk: fd_lib.per_label_logits(
-                self.apply_fn, w, s, xk, yk, hp.n_classes))(wk_m, sk_m,
-                                                            x_m, y_m)
-        owns_m = jnp.logical_and(owns_m, mask_m.astype(bool)[:, None])
-        # non-gathered lanes scatter as (zeros, False): identical Eq. 5 terms
-        # to the dense masked round's (finite table, False-by-mask) lanes
-        tg, n_own = fd_lib.aggregate_fd(scatter_zeros(tk_m, K, idx),
-                                        scatter_zeros(owns_m, K, idx))
-        rngs_m = jnp.take(client_keys(rng, ctx, K), idx, axis=0)
-
-        def per_client(w, s, o, xk, yk, tkk, rk):
-            tgt = fd_lib.distill_targets(tg, tkk, n_own, yk)
-            return local_update(spec, w, s, o, xk, yk, rk,
-                                distill_extra=tgt, gamma=hp.gamma)
-
-        wk_n, sk_n, ok_n, losses = jax.vmap(per_client)(wk_m, sk_m, ok_m,
-                                                        x_m, y_m, tk_m, rngs_m)
-        wk_m, sk_m, ok_m = select_clients(mask_m, (wk_n, sk_n, ok_n),
-                                          (wk_m, sk_m, ok_m))
-        wk, sk, ok = scatter_clients((wk_m, sk_m, ok_m), (wk, sk, ok), idx)
-        metrics = {"update_loss": masked_mean(scatter_zeros(losses, K, idx),
-                                              ctx.mask),
-                   "global_logit": tg}
-        return RoundState(clients=ClientState(wk, sk, ok)), metrics
+        return RoundState(clients=clients), metrics
 
     def upload_payload(self, state: RoundState, ctx: BatchCtx):
         """One client's upload: the per-class average logit table (C, C)."""
@@ -813,31 +690,21 @@ class FedAvgAlgorithm:
         spec = self._spec()
         w0, s0 = state.server.params, state.server.model_state
         K = ctx.x.shape[0]
-        masked = present(ctx.mask)
-        sparse = (masked and ctx.active_budget is not None
-                  and ctx.active_budget < K)
+        lanes = LanePlan.for_round(ctx)
 
         def per_client(xk, yk, rk):
             opt_state = spec.opt.init(w0)
             return local_update(spec, w0, s0, opt_state, xk, yk, rk)
 
-        if sparse:
-            # client state is ephemeral: only the <= m active lanes train;
-            # their results scatter into exact zeros, which the Eq. 3
-            # weighted average multiplies by an exact-zero weight anyway
-            idx = active_indices(ctx.mask, ctx.active_budget)
-            x_m, y_m = gather_clients((ctx.x, ctx.y), idx)
-            rngs_m = jnp.take(client_keys(rng, ctx, K), idx, axis=0)
-            wk_m, sk_m, _, losses_m = jax.vmap(per_client)(x_m, y_m, rngs_m)
-            wk = jax.tree.map(lambda a: scatter_zeros(a, K, idx), wk_m)
-            sk = jax.tree.map(lambda a: scatter_zeros(a, K, idx), sk_m)
-            losses = scatter_zeros(losses_m, K, idx)
-        else:
-            rngs = client_keys(rng, ctx, K)
-            wk, sk, _, losses = jax.vmap(per_client)(ctx.x, ctx.y, rngs)
+        # client state is ephemeral: only the computed lanes train, and the
+        # lanes not computed join the Eq. 3 average as exact zeros under an
+        # exact-zero weight
+        x, y = lanes.take((ctx.x, ctx.y))
+        wk, sk, _, losses = jax.vmap(per_client)(x, y, lanes.keys(rng, ctx))
+        wk, sk = lanes.zeros((wk, sk))
         weights = (jnp.ones((K,), jnp.float32)
                    if isinstance(ctx.weights, tuple) else ctx.weights)
-        if masked:
+        if lanes.mask is not None:
             # absent clients carry exactly zero weight in the Eq. 3 average
             # (client state is ephemeral in FedAvg, so masking the average IS
             # the partial-participation round); stale async contributions are
@@ -847,9 +714,8 @@ class FedAvgAlgorithm:
                 self.hp.staleness_decay, base=weights)
         new_w0 = weighted_average(wk, weights)
         new_s0 = weighted_average(sk, weights)
-        metrics = {"update_loss": (masked_mean(losses, ctx.mask) if masked
-                                   else jnp.mean(losses))}
-        if masked:
+        metrics = {"update_loss": lanes.mean(losses)}
+        if lanes.mask is not None:
             metrics["participants"] = jnp.sum(ctx.mask.astype(jnp.float32))
         return RoundState(server=ServerState(new_w0, new_s0)), metrics
 

@@ -34,14 +34,15 @@ from .llm_dsfl import (LLMDsflHP, dsfl_exchange, dsfl_round_finish,
                        predict_open_probs)
 
 
-def _participation(ctx: BatchCtx, decay: float):
-    """(K,) aggregation weights from the sim's mask/stale ctx fields, or
-    None for the exact full-participation path.  Shares the aggregation
-    helper's all-zero fallback (decay 0 + all-stale cohort -> raw mask)."""
-    if not present(ctx.mask):
-        return None
-    return participation_weights(
-        ctx.mask, ctx.stale if present(ctx.stale) else None, decay)
+def _participation(ctx: BatchCtx, decay: float) -> dict:
+    """The round steps' participation arguments from the sim's ctx fields:
+    (K,) aggregation weights (None for the exact full-participation path;
+    the aggregation helper's all-zero fallback, decay 0 + all-stale cohort
+    -> raw mask), the mask, and the static budget."""
+    mask = ctx.mask if present(ctx.mask) else None
+    weights = None if mask is None else participation_weights(
+        mask, ctx.stale if present(ctx.stale) else None, decay)
+    return dict(weights=weights, mask=mask, active_budget=ctx.active_budget)
 
 
 def _take_open(open_x, o_idx):
@@ -126,9 +127,8 @@ class LLMDSFLAlgorithm:
         open_b = _take_open(ctx.open_x, ctx.o_idx)
         return self._result(*dsfl_round_step(
             self.cfg, state.clients.params, ctx.x, open_b, self.hp,
-            weights=_participation(ctx, self.hp.staleness_decay),
-            mask=ctx.mask if present(ctx.mask) else None,
-            active_budget=ctx.active_budget, probe_rows=self.probe_rows))
+            probe_rows=self.probe_rows,
+            **_participation(ctx, self.hp.staleness_decay)))
 
     @staticmethod
     def _result(new, loss, *probe):
@@ -150,9 +150,7 @@ class LLMDSFLAlgorithm:
         open_b = _take_open(ctx.open_x, ctx.o_idx)
         return dsfl_exchange(
             self.cfg, state.clients.params, open_b, self.hp,
-            weights=_participation(ctx, self.hp.staleness_decay),
-            mask=ctx.mask if present(ctx.mask) else None,
-            active_budget=ctx.active_budget)
+            **_participation(ctx, self.hp.staleness_decay))
 
     def round_finish(self, state: RoundState, ctx: BatchCtx, inflight, rng):
         """Consume the in-flight exchange: aggregate the teacher and run the
@@ -162,9 +160,8 @@ class LLMDSFLAlgorithm:
         open_b = _take_open(ctx.open_x, ctx.o_idx)
         return self._result(*dsfl_round_finish(
             self.cfg, state.clients.params, ctx.x, open_b, inflight, self.hp,
-            weights=_participation(ctx, self.hp.staleness_decay),
-            mask=ctx.mask if present(ctx.mask) else None,
-            active_budget=ctx.active_budget, probe_rows=self.probe_rows))
+            probe_rows=self.probe_rows,
+            **_participation(ctx, self.hp.staleness_decay)))
 
     def upload_payload(self, state: RoundState, ctx: BatchCtx):
         """One client's upload: per-token class distributions on o_r —
@@ -209,9 +206,7 @@ class LLMFedAvgAlgorithm:
         del rng
         new, loss = fedavg_round_step(
             self.cfg, state.clients.params, ctx.x, self.hp.lr,
-            weights=_participation(ctx, self.hp.staleness_decay),
-            mask=ctx.mask if present(ctx.mask) else None,
-            active_budget=ctx.active_budget)
+            **_participation(ctx, self.hp.staleness_decay))
         return RoundState(clients=ClientState(params=new)), {"loss": loss}
 
     def upload_payload(self, state: RoundState, ctx: BatchCtx):

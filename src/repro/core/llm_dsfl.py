@@ -34,9 +34,7 @@ from ..models.shardctx import current_mesh
 from ..obs import trace as obs
 from .aggregation import sa, topk_compress, weighted_sa
 from .hierarchy import hierarchical_weighted_era, hierarchical_weighted_sa
-from .algorithms import (AGGREGATE, DISTILL, PREDICT, UPDATE, active_indices,
-                         gather_clients, masked_mean, scatter_clients,
-                         scatter_zeros, select_clients)
+from .algorithms import AGGREGATE, DISTILL, PREDICT, UPDATE, LanePlan
 from .losses import (HIGHEST, distill_xent, pinned_sum, topk_distill_xent,
                      xent_int_labels)
 
@@ -141,13 +139,16 @@ def predict_open_probs(cfg: ModelConfig, params, open_batch):
                           ).astype(jnp.bfloat16)
 
 
-def _is_sparse_round(K: int, hp: LLMDsflHP, weights, active_budget) -> bool:
-    """The (static, trace-time) predicate routing a round through the
-    participation-sparse gather plane.  Shared by the exchange and finish
-    halves so a split round can never disagree with the fused one about
-    which plane it is on."""
-    return (weights is not None and active_budget is not None
-            and active_budget < K and hp.topk is None)
+def _lanes(K: int, weights, mask, active_budget, topk=None) -> LanePlan:
+    """The round's `LanePlan`: full without ``weights``; else the
+    participants are ``mask`` or, without it, the clients of nonzero
+    weight (a stale participant whose weight decayed to zero still trains
+    and averages into the loss).  The top-k exchange keeps every lane: its
+    pinned pod-axis all-gather is shaped by the full client axis."""
+    if weights is None:
+        return LanePlan(K)
+    act = (weights if mask is None else mask).astype(jnp.float32) > 0
+    return LanePlan.of(K, act, active_budget, dense=topk is not None)
 
 
 @jax.named_scope(PREDICT)
@@ -164,9 +165,9 @@ def dsfl_exchange(cfg: ModelConfig, stacked_params, open_batch,
       * ``hp.topk``: the pod-gathered ``(values, indices)`` pair — the
         (K, B, S, k) compressed uploads after the explicit shard_map
         all-gather (k*(4+4) bytes/token of inter-pod traffic);
-      * dense: the full (K, B, S, V) probability stack;
-      * participation-sparse: the (m, B, S, V) active-lane stack (the
-        finish leg scatters it into exact zeros).
+      * dense: the (lanes, B, S, V) probability stack of the computed
+        lanes — all K, or the m active ones on the participation-sparse
+        plane (the finish leg scatters those into exact zeros).
 
     Splitting here is what lets the engine's pipelined scan issue round
     r's all-gather before round r's compute leg: the buffers returned
@@ -178,15 +179,9 @@ def dsfl_exchange(cfg: ModelConfig, stacked_params, open_batch,
     ``dsfl_round_finish(..., dsfl_exchange(...))``, so fused and split
     rounds are the same jaxpr and the parity pins stay bitwise."""
     K = jax.tree.leaves(stacked_params)[0].shape[0]
-    if _is_sparse_round(K, hp, weights, active_budget):
-        act = weights if mask is None else mask
-        idx = active_indices(act, active_budget)
-        params_m = gather_clients(stacked_params, idx)
-        probs_m = jax.vmap(lambda p: predict_open_probs(cfg, p, open_batch)
-                           )(params_m)                      # (m, B, S, V)
-        return (probs_m,)
+    lanes = _lanes(K, weights, mask, active_budget, hp.topk)
     probs = jax.vmap(lambda p: predict_open_probs(cfg, p, open_batch)
-                     )(stacked_params)                     # (Kc, B, S, V)
+                     )(lanes.take(stacked_params))         # (Kc, B, S, V)
     if hp.topk is not None:
         tv, ti = jax.vmap(lambda pr: topk_compress(pr, hp.topk))(probs)
         # force pod-replication of the SMALL uploads (the all-gather is the
@@ -226,10 +221,10 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
     was sharpened from (`client_mean`, float32) at those tokens, (n, V)."""
     from ..models.shardctx import constrain
     K = jax.tree.leaves(stacked_params)[0].shape[0]
-    if _is_sparse_round(K, hp, weights, active_budget):
-        return _dsfl_finish_sparse(cfg, stacked_params, private_batches,
-                                   open_batch, inflight, hp, weights, mask,
-                                   active_budget, probe_rows)
+    with jax.named_scope(UPDATE):
+        lanes = _lanes(K, weights, mask, active_budget, hp.topk)
+        params = lanes.take(stacked_params)
+        batches = lanes.take(private_batches)
     if hp.topk is not None:
         tv, ti = inflight
         with jax.named_scope(AGGREGATE):
@@ -252,20 +247,16 @@ def dsfl_round_finish(cfg: ModelConfig, stacked_params, private_batches,
     else:
         (probs,) = inflight
         with jax.named_scope(AGGREGATE):
-            teacher, probe = _aggregate(probs, hp, weights, probe_rows)
+            teacher, probe = _aggregate(lanes.zeros(probs), hp, weights,
+                                        probe_rows)
 
     new_params, losses = jax.vmap(
         lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
-    )(stacked_params, private_batches)
-    if weights is not None:
-        # absent clients neither update nor average into the loss
-        m = (weights if mask is None else mask).astype(jnp.float32) > 0
-        with jax.named_scope(UPDATE):
-            new_params = select_clients(m, new_params, stacked_params)
-        loss = masked_mean(losses, m)
-    else:
-        loss = jnp.mean(losses)
-    return _with_probe(new_params, loss, probe)
+    )(params, batches)
+    # absent clients neither update nor average into the loss
+    with jax.named_scope(UPDATE):
+        new_params = lanes.put(lanes.keep(new_params, params), stacked_params)
+    return _with_probe(new_params, lanes.mean(losses), probe)
 
 
 def dsfl_round_step(cfg: ModelConfig, stacked_params, private_batches,
@@ -320,43 +311,6 @@ def _with_probe(new_params, loss, probe):
     """The round's results, with the aggregate at the probe rows when
     asked for."""
     return (new_params, loss) if probe is None else (new_params, loss, probe)
-
-
-def _dsfl_finish_sparse(cfg: ModelConfig, stacked_params, private_batches,
-                        open_batch, inflight, hp: LLMDsflHP, weights, mask,
-                        active_budget: int, probe_rows=None):
-    """Participation-sparse finish leg: same gather -> compute -> scatter
-    plane as `algorithms.DSFLAlgorithm._sparse_round`, along the
-    pod-sharded client axis.  Bitwise identical to the dense ``weights=``
-    round (tests/test_llm_dsfl.py): active lanes see the same per-client
-    math, and the scattered zero lanes multiply against the same
-    exact-zero aggregation weights the dense stack's lanes do.  ``idx``
-    is re-derived from the ctx (a pure, cheap argsort) rather than
-    carried in ``inflight``, so the exchange buffers stay O(m)."""
-    K = jax.tree.leaves(stacked_params)[0].shape[0]
-    act = weights if mask is None else mask
-    with jax.named_scope(UPDATE):
-        idx = active_indices(act, active_budget)
-        act_m = jnp.take(act, idx, axis=0)
-        params_m = gather_clients(stacked_params, idx)
-        batches_m = gather_clients(private_batches, idx)
-
-    (probs_m,) = inflight                                   # (m, B, S, V)
-    with jax.named_scope(AGGREGATE):
-        teacher, probe = _aggregate(scatter_zeros(probs_m, K, idx), hp,
-                                    weights, probe_rows)
-
-    new_m, losses_m = jax.vmap(
-        lambda p, b: dsfl_client_step(cfg, p, b, open_batch, teacher, hp)
-    )(params_m, batches_m)
-    with jax.named_scope(UPDATE):
-        new_m = select_clients(act_m.astype(jnp.float32) > 0, new_m,
-                               params_m)
-        new_params = scatter_clients(new_m, stacked_params, idx)
-    losses = scatter_zeros(losses_m, K, idx)
-    return _with_probe(new_params,
-                       masked_mean(losses, act.astype(jnp.float32) > 0),
-                       probe)
 
 
 def client_mean(probs, weights):
@@ -497,27 +451,17 @@ def fedavg_round_step(cfg: ModelConfig, stacked_params, private_batches,
     trains only those, and scatters into exact zeros — the Eq. 3 weighted
     mean multiplies the zero lanes by the same exact-zero weights the
     dense round's lanes get, so the result is bitwise identical."""
-    K = jax.tree.leaves(stacked_params)[0].shape[0]
-    if (weights is not None and active_budget is not None
-            and active_budget < K):
-        act = weights if mask is None else mask
-        idx = active_indices(act, active_budget)
-        new_m, losses_m = jax.vmap(
-            lambda p, b: sgd_train_step(cfg, p, b, lr)
-        )(gather_clients(stacked_params, idx),
-          gather_clients(private_batches, idx))
-        new_params = jax.tree.map(lambda a: scatter_zeros(a, K, idx), new_m)
-        losses = scatter_zeros(losses_m, K, idx)
-    else:
-        new_params, losses = jax.vmap(
-            lambda p, b: sgd_train_step(cfg, p, b, lr))(stacked_params,
-                                                        private_batches)
+    lanes = _lanes(jax.tree.leaves(stacked_params)[0].shape[0], weights,
+                   mask, active_budget)
+    new_params, losses = jax.vmap(
+        lambda p, b: sgd_train_step(cfg, p, b, lr)
+    )(lanes.take(stacked_params), lanes.take(private_batches))
+    new_params = lanes.zeros(new_params)
     if weights is None:
         avg = jax.tree.map(
             lambda leaf: jnp.mean(leaf.astype(jnp.float32), axis=0,
                                   keepdims=True).astype(leaf.dtype),
             new_params)
-        loss = jnp.mean(losses)
     else:
         w = weights.astype(jnp.float32)
         # dot-lowered total: bitwise-stable across the dense/sparse programs
@@ -527,8 +471,7 @@ def fedavg_round_step(cfg: ModelConfig, stacked_params, private_batches,
                                     leaf.astype(jnp.float32),
                                     precision=HIGHEST
                                     )[None].astype(leaf.dtype), new_params)
-        m = (weights if mask is None else mask).astype(jnp.float32) > 0
-        loss = masked_mean(losses, m)
+    loss = lanes.mean(losses)
     broad = jax.tree.map(lambda a, ref: jnp.broadcast_to(a, ref.shape),
                          avg, new_params)
     return broad, loss
