@@ -57,9 +57,69 @@ def batchnorm(p, s, x, train: bool, momentum=0.9, eps=1e-5):
     return y, ns
 
 
-def maxpool2(x):
+def _max2(x):
     return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
                                  (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+
+
+def _windows(x):
+    """``x`` (NHWC) cut to its 2x2 windows, (N, H/2, 2, W/2, 2, C); VALID
+    pooling drops an odd last row or column."""
+    n, hh, ww, c = x.shape
+    h, w = hh // 2, ww // 2
+    return x[:, :2 * h, :2 * w, :].reshape(n, h, 2, w, 2, c)
+
+
+def _pool_pick(x):
+    """(max, pick) of each 2x2 window of ``x``: its maximum, and as int8
+    the row-major slot (0..3) of the element XLA's ``select_and_scatter``
+    picks for ``reduce_window``'s own gradient.  The window is walked in
+    row-major order and a later element replaces the one held only where
+    the held one is not ``>=`` it, so ties go to the first."""
+    win = _windows(x)
+    held = win[:, :, 0, :, 0, :]
+    pick = jnp.zeros(held.shape, jnp.int8)
+    for k, (i, j) in enumerate(((0, 1), (1, 0), (1, 1)), start=1):
+        new = win[:, :, i, :, j, :]
+        keep = held >= new
+        held, pick = jnp.where(keep, held, new), jnp.where(keep, pick, k)
+    return held, pick
+
+
+def _unpool(pick, g, shape):
+    """Each entry of ``g`` at the slot ``pick`` names in its window of an
+    array of ``shape``, zeros elsewhere and in dropped rows and columns."""
+    n, hh, ww, c = shape
+    h, w = hh // 2, ww // 2
+    full = (n, h, 2, w, 2, c)
+    slot = (2 * jax.lax.broadcasted_iota(jnp.int8, (1, 1, 2, 1, 2, 1), 2)
+            + jax.lax.broadcasted_iota(jnp.int8, (1, 1, 2, 1, 2, 1), 4))
+    dx = jnp.where(
+        jnp.broadcast_to(pick[:, :, None, :, None, :], full) == slot,
+        jnp.broadcast_to(g[:, :, None, :, None, :], full),
+        jnp.zeros((), g.dtype)).reshape(n, 2 * h, 2 * w, c)
+    return jnp.pad(dx, ((0, 0), (0, hh - 2 * h), (0, ww - 2 * w), (0, 0)))
+
+
+def maxpool2_grad(x, g):
+    """Cotangent of `maxpool2` at ``x`` for the output cotangent ``g``:
+    ``reduce_window``'s own gradient, bit for bit.  The pick is an index
+    at the pooled size and the unpooling one select at full size, both
+    element-wise, so XLA fuses them into the backward of whatever made
+    ``x``; ``select_and_scatter`` would run as an unfused pass over it."""
+    return _unpool(_pool_pick(x)[1], g, x.shape)
+
+
+@jax.custom_vjp
+def maxpool2(x):
+    """2x2 max pool, stride 2, VALID (NHWC): ``reduce_window``'s forward,
+    with `maxpool2_grad`, equal to ``reduce_window``'s own gradient, for
+    its backward."""
+    return _max2(x)
+
+
+maxpool2.defvjp(lambda x: (_max2(x), x),
+                lambda x, g: (maxpool2_grad(x, g),))
 
 
 # -------------------------------------------------------------- MNIST CNN ----
